@@ -166,6 +166,8 @@ print("RESULT " + json.dumps(out))
 
 def _subprocess_env(devices: int) -> dict:
     env = dict(os.environ)
+    # a CPU-only rehearsal on forced host devices: never claim a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     # src for repro.*, the repo root for benchmarks.common (the shared
     # timing harness the wall-clock subprocess reuses)

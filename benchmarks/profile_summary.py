@@ -229,6 +229,8 @@ def _generate(outdir: str, timeout: int = 3000) -> Dict[str, str]:
             .replace("OUTDIR", repr(outdir))
             .replace("MODES_TUPLE", repr(PROF_MODES)))
     env = dict(os.environ)
+    # a CPU-only rehearsal on forced host devices: never claim a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={PROF_K}"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src"), ROOT]

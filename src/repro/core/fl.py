@@ -13,8 +13,7 @@ Two runtimes share the same math:
 
 * ``make_fl_round`` — the production mapping: one client cohort per
   (``pod``, ``data``) mesh shard, model tensor-parallel over ``model``
-  (GSPMD auto axes inside ``shard_map`` where the jax version supports
-  partial-manual lowering; replicated on the 0.4.37 floor).  Each cohort
+  (GSPMD auto axes inside a partial-auto ``shard_map``).  Each cohort
   runs I local SGD steps, quantizes its delta, survives with prob. 1−q, and
   the cohorts aggregate with a psum whose WIRE FORMAT is selectable —
   ``collective=`` or ``QuantConfig.wire_format``:
@@ -564,18 +563,16 @@ def make_fl_round(model, config: Config, mesh, *,
     to it as ``metrics["wire_phase_bits_per_param"]`` (e.g. rsag's
     reduce_scatter/all_gather legs — ``population.telemetry``).
 
-    ``tap`` (a host callable taking (metrics dict, flat shard index,
-    round index) — usually ``obs.shard0_sink_tap(sink)``) streams each
-    round's metrics out of the shard_map via ``io_callback`` while the
-    step executes; the callback fires on every shard, so the host adapter
-    filters to shard 0 (one record per round).  A TAPPED round fn takes
-    one extra trailing argument — a replicated int32 ``step`` scalar —
-    whose value stamps the streamed record: the callback is unordered
-    (an ordered one threads a token through the jit root tuple, crashing
-    0.4.37 sharding propagation under ``out_shardings``), so with async
-    dispatch the host cannot number records by arrival.  ``tap=None``
-    traces nothing — the lowered HLO is byte-identical to a no-obs build,
-    and the signature stays exactly as documented above.
+    ``tap`` (a host callable taking (metrics dict, round index) —
+    usually ``obs.step_sink_tap(sink)``) streams each round's metrics out
+    of the jitted step via ``io_callback`` while it executes; the callback
+    sits after the shard_map, on the replicated metrics, so it fires once
+    per round.  A TAPPED round fn takes one extra trailing argument — a
+    replicated int32 ``step`` scalar — whose value stamps the streamed
+    record: the callback is unordered, so with async dispatch the host
+    cannot number records by arrival.  ``tap=None`` traces nothing — the
+    lowered HLO is byte-identical to a no-obs build, and the signature
+    stays exactly as documented above.
     """
     fl = config.fl
     qcfg = config.quant
@@ -646,32 +643,16 @@ def make_fl_round(model, config: Config, mesh, *,
             rng = jax.random.fold_in(rng, jax.lax.axis_index(a))
         return rng
 
-    def _flat_shard():
-        # the flat shard index over ALL Manual mesh axes, row-major in mesh
-        # order — not just the data axes: pre-0.7 jax spells partial-auto
-        # as fully-Manual, so the body replicates over model-parallel axes
-        # and every replica of data-shard 0 would otherwise claim shard 0
-        manual = compat.manual_axes()
-        shard = jnp.int32(0)
-        for a in mesh.axis_names:
-            if a in manual:
-                shard = shard * int(mesh.shape[a]) + jax.lax.axis_index(a)
-        return shard
-
     def _cohort_index():
         # flat cohort index over the DATA axes only — the identity every
-        # model-axis replica of one cohort shares.  Cohort-shaped vectors
-        # (FleetRoundInfo.lam, length num_shards) MUST be indexed with
-        # this, never _flat_shard(): on the pre-0.7 fully-Manual floor
-        # the latter also ranges over model axes, so the gather would
-        # OOB-clamp and replicas of one cohort would read different λ —
-        # divergent "replicated" outputs that check_vma=False hides.
+        # model-axis replica of one cohort shares; cohort-shaped vectors
+        # (FleetRoundInfo.lam, length num_shards) are indexed with it
         shard = jnp.int32(0)
         for a, s in zip(axes, axis_sizes):
             shard = shard * s + jax.lax.axis_index(a)
         return shard
 
-    def local_round(params, batch, rng, step=None):
+    def local_round(params, batch, rng):
         rng = _shard_rng(rng)
         lam = ch.sample_packet_success(jax.random.fold_in(rng, 11), (),
                                        config.channel.error_prob)
@@ -679,10 +660,9 @@ def make_fl_round(model, config: Config, mesh, *,
                                                           rng, lam)
         metrics = pop_tel.distributed_metrics(
             plan, loss=mean_loss, survivors=survivors)
-        obs_tap.emit_on_shard0(metrics, _flat_shard(), step, tap)
         return new_params, metrics
 
-    def fleet_round(params, batch, rng, fleet, step=None):
+    def fleet_round(params, batch, rng, fleet):
         # the fleet update is REPLICATED: identical inputs (fleet, raw rng)
         # on every shard compute the identical selection, so each shard
         # just reads its own λ at its flat cohort index — no collective.
@@ -716,7 +696,6 @@ def make_fl_round(model, config: Config, mesh, *,
                 outage_sel=info.outage_sel, cost_sel=info.cost_sel,
                 harvest_j=info.harvest_j,
                 error_prob=config.channel.error_prob))
-        obs_tap.emit_on_shard0(metrics, _flat_shard(), step, tap)
         return new_params, metrics, fleet
 
     P = jax.sharding.PartitionSpec
@@ -726,20 +705,29 @@ def make_fl_round(model, config: Config, mesh, *,
     metric_specs = jax.tree_util.tree_map(
         lambda _: P(), pop_tel.distributed_metrics_structure(plan,
                                                              with_fleet))
-    # a tapped round takes one extra trailing arg: the replicated int32
-    # ``step`` scalar that stamps the streamed record (see ``tap`` above)
-    step_specs = (P(),) if tap is not None else ()
     if with_fleet:
-        return compat.shard_map(
+        round_fn = compat.shard_map(
             fleet_round, mesh=mesh,
-            in_specs=(P(), batch_specs, P(), P()) + step_specs,
+            in_specs=(P(), batch_specs, P(), P()),
             out_specs=(P(), metric_specs, P()),
             check_vma=False, axis_names=set(axes))
-    return compat.shard_map(
-        local_round, mesh=mesh,
-        in_specs=(P(), batch_specs, P()) + step_specs,
-        out_specs=(P(), metric_specs),
-        check_vma=False, axis_names=set(axes))
+    else:
+        round_fn = compat.shard_map(
+            local_round, mesh=mesh,
+            in_specs=(P(), batch_specs, P()),
+            out_specs=(P(), metric_specs),
+            check_vma=False, axis_names=set(axes))
+    if tap is None:
+        return round_fn
+
+    def tapped_round(*args):
+        # the trailing replicated int32 ``step`` stamps the streamed record
+        *args, step = args
+        out = round_fn(*args)
+        obs_tap.emit_step(out[1], step, tap)
+        return out
+
+    return tapped_round
 
 
 def _batch_structure(model, config: Config):

@@ -26,7 +26,7 @@ def _aggregate_kernel(u_ref, w_ref, out_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "eps"))
 def masked_aggregate(updates: jax.Array, weights: jax.Array, *,
-                     eps: float = 1e-12, interpret: bool = True) -> jax.Array:
+                     eps: float = 1e-12, interpret: bool) -> jax.Array:
     """updates (K, D) f32/int; weights (K,) -> (D,) f32 (paper eq. 6)."""
     K, D = updates.shape
     pad_d = (BLOCK_D - D % BLOCK_D) % BLOCK_D

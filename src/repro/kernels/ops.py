@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the kernel
-body executes as pure-Python-traced jnp, proving correctness; on a real TPU
-``interpret=False`` compiles to Mosaic.  ``_INTERPRET`` auto-detects.
+This is the one place that picks how a kernel runs: compiled to Mosaic
+(``interpret=False``) when the default backend is a TPU, and the Pallas
+interpreter everywhere else (the CPU test backend).  The choice is made
+per call, when the wrapper traces — never at import, so importing
+``repro.kernels`` does not initialise a backend or claim a device.
 """
 from __future__ import annotations
 
@@ -15,7 +17,10 @@ from repro.kernels import qmatmul as _qmm
 from repro.kernels import quantize as _quant
 from repro.obs import trace as _obs_trace
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Interpret the kernels unless the default backend is a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def stochastic_quantize_codes(x: jax.Array, key: jax.Array, bits: int, *,
@@ -24,20 +29,21 @@ def stochastic_quantize_codes(x: jax.Array, key: jax.Array, bits: int, *,
     with _obs_trace.phase_span("pallas/stochastic_quantize_codes"):
         return _quant.stochastic_quantize_codes(x, u, bits, clip=clip,
                                                 stochastic=stochastic,
-                                                interpret=_INTERPRET)
+                                                interpret=_interpret())
 
 
 def stochastic_quantize(x: jax.Array, key: jax.Array, bits: int, *,
                         clip: float = 1.0, stochastic: bool = True) -> jax.Array:
     """Quantize-dequantize through the kernel pair (f32 out)."""
     codes = stochastic_quantize_codes(x, key, bits, clip=clip, stochastic=stochastic)
-    return _quant.dequantize_codes(codes, bits, clip=clip, interpret=_INTERPRET)
+    return _quant.dequantize_codes(codes, bits, clip=clip,
+                                   interpret=_interpret())
 
 
 def dequantize_codes(codes: jax.Array, bits: int, *, clip: float = 1.0) -> jax.Array:
     with _obs_trace.phase_span("pallas/dequantize_codes"):
         return _quant.dequantize_codes(codes, bits, clip=clip,
-                                       interpret=_INTERPRET)
+                                       interpret=_interpret())
 
 
 def quantize_pack(x: jax.Array, key: jax.Array, bits: int, *,
@@ -55,7 +61,7 @@ def quantize_pack(x: jax.Array, key: jax.Array, bits: int, *,
         return _pack.quantize_pack(x, u, bits, clip=clip,
                                    lane_bits=lane_bits,
                                    stochastic=stochastic,
-                                   interpret=_INTERPRET)
+                                   interpret=_interpret())
 
 
 def quantize_pack_chunk(x: jax.Array, key: jax.Array, bits: int, *,
@@ -77,7 +83,7 @@ def quantize_pack_chunk(x: jax.Array, key: jax.Array, bits: int, *,
                                          lane_bits=lane_bits,
                                          stochastic=stochastic,
                                          num_chunks=num_chunks, bias=bias,
-                                         interpret=_INTERPRET)
+                                         interpret=_interpret())
 
 
 def repack(packed: jax.Array, acc: jax.Array, bits: int, size: int, *,
@@ -88,7 +94,8 @@ def repack(packed: jax.Array, acc: jax.Array, bits: int, size: int, *,
     (the rsag collective's lane-symmetric bias)."""
     with _obs_trace.phase_span("pallas/repack"):
         return _pack.repack(packed, acc, bits, size, lane_bits=lane_bits,
-                            sum_of=sum_of, bias=bias, interpret=_INTERPRET)
+                            sum_of=sum_of, bias=bias,
+                            interpret=_interpret())
 
 
 def pack_sums(codes: jax.Array, bits: int, *, lane_bits: int = 0,
@@ -98,7 +105,7 @@ def pack_sums(codes: jax.Array, bits: int, *, lane_bits: int = 0,
     with _obs_trace.phase_span("pallas/pack_sums"):
         return _pack.pack_sums(codes, bits, lane_bits=lane_bits,
                                sum_of=sum_of, bias=bias,
-                               interpret=_INTERPRET)
+                               interpret=_interpret())
 
 
 def unpack_dequantize(packed: jax.Array, bits: int, size: int, *,
@@ -112,17 +119,17 @@ def unpack_dequantize(packed: jax.Array, bits: int, size: int, *,
     with _obs_trace.phase_span("pallas/unpack_dequantize"):
         return _pack.unpack_dequantize(packed, bits, size, clip=clip,
                                        lane_bits=lane_bits, sum_of=sum_of,
-                                       bias=bias, interpret=_INTERPRET)
+                                       bias=bias, interpret=_interpret())
 
 
 def qmatmul(x_q: jax.Array, w_q: jax.Array, sx, sw) -> jax.Array:
     with _obs_trace.phase_span("pallas/qmatmul"):
         return _qmm.qmatmul(x_q, w_q, jnp.float32(sx), jnp.float32(sw),
-                            interpret=_INTERPRET)
+                            interpret=_interpret())
 
 
 def masked_aggregate(updates: jax.Array, weights: jax.Array,
                      eps: float = 1e-12) -> jax.Array:
     with _obs_trace.phase_span("pallas/masked_aggregate"):
         return _agg.masked_aggregate(updates, weights, eps=eps,
-                                     interpret=_INTERPRET)
+                                     interpret=_interpret())

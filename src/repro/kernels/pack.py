@@ -48,34 +48,44 @@ BLOCK_ROWS = 128
 LANES = 128
 
 
+def _word_index(block_rows: int):
+    """Flat word index of every element of this grid step's (block_rows,
+    LANES) word block."""
+    shape = (block_rows, LANES)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (pl.program_id(0) * block_rows + row) * LANES + col
+
+
+def _quantize(x, u, *, gain: float, g: int, stochastic: bool):
+    """f32 block -> int32 codes in [-g, g-1] (the clip interval is folded
+    into ``gain`` by the caller)."""
+    xq = jnp.clip(x.astype(jnp.float32), -1.0, 1.0) * gain
+    rounded = jnp.floor(xq + u) if stochastic else jnp.round(xq)
+    return jnp.clip(rounded, -g, g - 1).astype(jnp.int32)
+
+
 def _quantize_pack_kernel(x_ref, u_ref, words_ref, *, gain: float, g: int,
                           lane: int, cpw: int, n: int, W: int,
                           stochastic: bool):
-    x = x_ref[...].astype(jnp.float32)                    # (cpw, BR, LANES)
-    xq = jnp.clip(x, -1.0, 1.0) * gain   # clip interval folded into gain
-    if stochastic:
-        rounded = jnp.floor(xq + u_ref[...])
-    else:
-        rounded = jnp.round(xq)
-    codes = jnp.clip(rounded, -g, g - 1).astype(jnp.int32)
-
-    shape = x.shape                                        # (cpw, BR, LANES)
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-    plane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    w = (pl.program_id(0) * shape[1] + row) * shape[2] + col   # word index
-    valid = (w < W) & (plane * W + w < n)                  # real elements only
-    biased = jnp.where(valid, codes + g, 0).astype(jnp.uint32)
-
-    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * lane).reshape(cpw, 1, 1)
-    words_ref[...] = jnp.sum(biased << shifts, axis=0, dtype=jnp.uint32)
+    w = _word_index(words_ref.shape[0])
+    words = jnp.zeros(words_ref.shape, jnp.uint32)
+    # the cpw planes are static: an unrolled modular shift-add per plane
+    # (Mosaic has no unsigned reduction and no 1-D -> 3-D shape cast)
+    for j in range(cpw):
+        codes = _quantize(x_ref[j], u_ref[j], gain=gain, g=g,
+                          stochastic=stochastic)
+        valid = (w < W) & (j * W + w < n)                  # real elements only
+        biased = jnp.where(valid, codes + g, 0).astype(jnp.uint32)
+        words = words + (biased << jnp.uint32(j * lane))
+    words_ref[...] = words
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "clip", "lane_bits",
                                              "stochastic", "interpret"))
 def quantize_pack(x: jax.Array, u: jax.Array, bits: int, *, clip: float = 1.0,
                   lane_bits: int = 0, stochastic: bool = True,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool) -> jax.Array:
     """Fused quantize+pack: f32 ``x`` with noise ``u`` -> uint32 words (W,).
 
     Bit-exact with ``pack_codes(quantize_codes(x, ·), ·)`` for every size
@@ -119,13 +129,13 @@ def quantize_pack(x: jax.Array, u: jax.Array, bits: int, *, clip: float = 1.0,
 def _unpack_dequantize_kernel(words_ref, out_ref, *, lane: int, cpw: int,
                               bias: int, inv_gain: float):
     words = words_ref[...]                                  # (BR, LANES) u32
-    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * lane).reshape(cpw, 1, 1)
     mask = jnp.uint32(2 ** lane - 1)
-    lanes = (words[None] >> shifts) & mask                  # (cpw, BR, LANES)
-    # modular uint32 un-bias (exact for biases up to the full lane width,
-    # e.g. the rsag lane_bias 2^(lane-1) at lane 32)
-    vals = (lanes - jnp.uint32(bias)).astype(jnp.int32)
-    out_ref[...] = vals.astype(jnp.float32) * inv_gain
+    for j in range(cpw):
+        lanes = (words >> jnp.uint32(j * lane)) & mask
+        # modular uint32 un-bias (exact for biases up to the full lane width,
+        # e.g. the rsag lane_bias 2^(lane-1) at lane 32)
+        vals = (lanes - jnp.uint32(bias)).astype(jnp.int32)
+        out_ref[j] = vals.astype(jnp.float32) * inv_gain
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "size", "clip",
@@ -134,7 +144,7 @@ def _unpack_dequantize_kernel(words_ref, out_ref, *, lane: int, cpw: int,
 def unpack_dequantize(packed: jax.Array, bits: int, size: int, *,
                       clip: float = 1.0, lane_bits: int = 0, sum_of: int = 1,
                       bias: int | None = None,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """Fused unpack+dequantize: uint32 words -> flat f32 of length ``size``.
 
     ``sum_of`` un-biases an aggregated buffer (psum of ``sum_of`` packed
@@ -172,26 +182,21 @@ def unpack_dequantize(packed: jax.Array, bits: int, size: int, *,
 def _repack_kernel(words_ref, acc_ref, out_ref, *, lane: int, cpw: int,
                    bias: int, n: int, W: int):
     words = words_ref[...]                                  # (BR, LANES) u32
-    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * lane).reshape(cpw, 1, 1)
+    w = _word_index(words.shape[0])
     mask = jnp.uint32(2 ** lane - 1)
-    lanes = (words[None] >> shifts) & mask                  # (cpw, BR, LANES)
-    shape = lanes.shape
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-    plane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    w = (pl.program_id(0) * shape[1] + row) * shape[2] + col
-    valid = (w < W) & (plane * W + w < n)
-    # modular uint32 un-bias (exact for biases up to the full lane width)
-    vals = (lanes - jnp.uint32(bias)).astype(jnp.int32)
-    delta = jnp.where(valid, vals, 0)
-    out_ref[...] = acc_ref[...] + delta
+    for j in range(cpw):
+        lanes = (words >> jnp.uint32(j * lane)) & mask
+        valid = (w < W) & (j * W + w < n)
+        # modular uint32 un-bias (exact for biases up to the full lane width)
+        vals = (lanes - jnp.uint32(bias)).astype(jnp.int32)
+        out_ref[j] = acc_ref[j] + jnp.where(valid, vals, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "size", "lane_bits",
                                              "sum_of", "bias", "interpret"))
 def repack(packed: jax.Array, acc: jax.Array, bits: int, size: int, *,
            lane_bits: int = 0, sum_of: int = 1, bias: int | None = None,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool) -> jax.Array:
     """Fused mid-hop accumulate of the ring collective: unpack ``packed``
     (partial sums of ``sum_of`` codes, biased by sum_of·G per lane at the
     hop's ``lane_bits`` width) and add it into the flat int32 register tree
@@ -239,32 +244,44 @@ def repack(packed: jax.Array, acc: jax.Array, bits: int, size: int, *,
 
 def _quantize_pack_chunk_kernel(x_ref, u_ref, words_ref, codes_ref, *,
                                 gain: float, g: int, lane: int, K: int,
-                                cpw: int, C: int, Wc: int, br: int,
-                                bias: int, stochastic: bool):
-    x = x_ref[...].astype(jnp.float32)                 # (K·cpw, br, LANES)
-    xq = jnp.clip(x, -1.0, 1.0) * gain   # clip interval folded into gain
-    if stochastic:
-        rounded = jnp.floor(xq + u_ref[...])
-    else:
-        rounded = jnp.round(xq)
-    codes = jnp.clip(rounded, -g, g - 1).astype(jnp.int32)
-
+                                cpw: int, C: int, Wc: int, bias: int,
+                                stochastic: bool):
     # all chunks ride in the SAME grid step (leading dim = K·cpw planes):
     # one row-stripe grid keeps the step count O(R/br) instead of O(K·R/br)
-    shape = (K, cpw) + x.shape[1:]                     # (K, cpw, br, LANES)
-    codes = codes.reshape(shape)
-    plane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-    col = jax.lax.broadcasted_iota(jnp.int32, shape, 3)
-    w = (pl.program_id(0) * br + row) * shape[3] + col     # word index
-    valid = (w < Wc) & (plane * Wc + w < C)            # real elements only
-    codes_ref[...] = jnp.where(valid, codes, 0).reshape(K * cpw, br, -1)
-    # modular uint32 biasing: exact even for the lane-symmetric 2^(lane-1)
-    # bias at lane 32 (an int32 add would overflow)
-    biased = jnp.where(valid, codes.astype(jnp.uint32) + jnp.uint32(bias),
-                       jnp.uint32(0))
-    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * lane).reshape(1, cpw, 1, 1)
-    words_ref[...] = jnp.sum(biased << shifts, axis=1, dtype=jnp.uint32)
+    w = _word_index(words_ref.shape[1])
+    for k in range(K):
+        words = jnp.zeros(words_ref.shape[1:], jnp.uint32)
+        for j in range(cpw):
+            p = k * cpw + j
+            codes = _quantize(x_ref[p], u_ref[p], gain=gain, g=g,
+                              stochastic=stochastic)
+            valid = (w < Wc) & (j * Wc + w < C)        # real elements only
+            codes_ref[p] = jnp.where(valid, codes, 0)
+            # modular uint32 biasing: exact even for the lane-symmetric
+            # 2^(lane-1) bias at lane 32 (an int32 add would overflow)
+            biased = jnp.where(valid,
+                               codes.astype(jnp.uint32) + jnp.uint32(bias),
+                               jnp.uint32(0))
+            words = words + (biased << jnp.uint32(j * lane))
+        words_ref[k] = words
+
+
+#: scoped-VMEM budget for the chunk megakernel's pipelined blocks (both
+#: inputs, both outputs, each double-buffered) — under v5e's 16 MiB default
+#: scoped limit with room left for the kernel body's per-plane temporaries
+_CHUNK_VMEM_BUDGET = 10 * 2 ** 20
+
+
+def _chunk_stripe_rows(K: int, cpw: int, Wc: int) -> int:
+    """Row stripe of the chunk megakernel: the largest power-of-two
+    multiple of 8 rows whose double-buffered blocks — x and u
+    (K·cpw planes f32), codes (K·cpw planes i32) and words (K planes u32)
+    — fit ``_CHUNK_VMEM_BUDGET``, and no wider than one chunk needs."""
+    bytes_per_row = 2 * LANES * 4 * (3 * K * cpw + K)
+    br = 8
+    while 2 * br * bytes_per_row <= _CHUNK_VMEM_BUDGET and br * LANES < Wc:
+        br *= 2
+    return br
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "clip", "lane_bits",
@@ -274,7 +291,7 @@ def quantize_pack_chunk(x: jax.Array, u: jax.Array, bits: int, *,
                         clip: float = 1.0, lane_bits: int = 0,
                         stochastic: bool = True, num_chunks: int = 1,
                         bias: int | None = None,
-                        interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                        interpret: bool) -> tuple[jax.Array, jax.Array]:
     """Fused collective front-end: quantize ``x``, split into ``num_chunks``
     chunks of C = ceil(n/num_chunks), and emit per chunk BOTH the packed
     uint32 wire words (num_chunks, ceil(C/cpw)) and the int32 codes
@@ -298,15 +315,7 @@ def quantize_pack_chunk(x: jax.Array, u: jax.Array, bits: int, *,
     cpw = 32 // lane
     C = -(-n // K)
     Wc = -(-C // cpw)
-    # the block spans every chunk (K·cpw leading planes): size the row
-    # stripe to an ~8 MB VMEM budget — shrink when the plane count is
-    # large, grow (fewer grid steps) while a wider stripe still fits and
-    # the chunk isn't already covered
-    br = BLOCK_ROWS
-    while K * cpw * br * LANES * 4 > 8 * 2 ** 20 and br > 8:
-        br //= 2
-    while K * cpw * br * LANES * 8 <= 8 * 2 ** 20 and br * LANES < Wc:
-        br *= 2
+    br = _chunk_stripe_rows(K, cpw, Wc)
     per_block = br * LANES
     Wc_pad = -(-Wc // per_block) * per_block
     R = Wc_pad // LANES
@@ -325,7 +334,7 @@ def quantize_pack_chunk(x: jax.Array, u: jax.Array, bits: int, *,
     g = int(2 ** (bits - 1))
     words, codes = pl.pallas_call(
         functools.partial(_quantize_pack_chunk_kernel, gain=gain, g=g,
-                          lane=lane, K=K, cpw=cpw, C=C, Wc=Wc, br=br,
+                          lane=lane, K=K, cpw=cpw, C=C, Wc=Wc,
                           bias=g if bias is None else int(bias),
                           stochastic=stochastic),
         grid=(R // br,),
@@ -350,24 +359,22 @@ def quantize_pack_chunk(x: jax.Array, u: jax.Array, bits: int, *,
 
 def _pack_sums_kernel(codes_ref, words_ref, *, bias: int, lane: int, cpw: int,
                       n: int, W: int):
-    codes = codes_ref[...]                                 # (cpw, BR, LANES)
-    shape = codes.shape
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-    plane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    w = (pl.program_id(0) * shape[1] + row) * shape[2] + col   # word index
-    valid = (w < W) & (plane * W + w < n)                  # real elements only
-    biased = jnp.where(valid, codes.astype(jnp.uint32) + jnp.uint32(bias),
-                       jnp.uint32(0))
-    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * lane).reshape(cpw, 1, 1)
-    words_ref[...] = jnp.sum(biased << shifts, axis=0, dtype=jnp.uint32)
+    w = _word_index(words_ref.shape[0])
+    words = jnp.zeros(words_ref.shape, jnp.uint32)
+    for j in range(cpw):
+        valid = (w < W) & (j * W + w < n)                  # real elements only
+        biased = jnp.where(valid,
+                           codes_ref[j].astype(jnp.uint32) + jnp.uint32(bias),
+                           jnp.uint32(0))
+        words = words + (biased << jnp.uint32(j * lane))
+    words_ref[...] = words
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "lane_bits", "sum_of",
                                              "bias", "interpret"))
 def pack_sums(codes: jax.Array, bits: int, *, lane_bits: int = 0,
               sum_of: int = 1, bias: int | None = None,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """Scatter-phase pack: int32 PARTIAL-SUM codes -> uint32 wire words.
 
     The rsag collective's outgoing payload builder: the running chunk

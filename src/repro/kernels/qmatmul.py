@@ -33,7 +33,7 @@ def _qmatmul_kernel(x_ref, w_ref, acc_ref, *, n_k: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def qmatmul(x_q: jax.Array, w_q: jax.Array, sx: jax.Array, sw: jax.Array, *,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool) -> jax.Array:
     """(M,K) int8 @ (K,N) int8 -> (M,N) f32 scaled by sx*sw (per-tensor)."""
     M, K = x_q.shape
     K2, N = w_q.shape

@@ -37,7 +37,7 @@ def _quantize_kernel(x_ref, u_ref, codes_ref, *, gain: float, g: int,
 @functools.partial(jax.jit, static_argnames=("bits", "clip", "stochastic", "interpret"))
 def stochastic_quantize_codes(x: jax.Array, u: jax.Array, bits: int, *,
                               clip: float = 1.0, stochastic: bool = True,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool) -> jax.Array:
     """Quantize ``x`` to int32 codes using uniform noise ``u`` (same shape)."""
     orig_shape = x.shape
     n = x.size
@@ -73,7 +73,7 @@ def _dequantize_kernel(codes_ref, out_ref, *, inv_gain: float):
 
 @functools.partial(jax.jit, static_argnames=("bits", "clip", "interpret"))
 def dequantize_codes(codes: jax.Array, bits: int, *, clip: float = 1.0,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     orig_shape = codes.shape
     n = codes.size
     per_block = BLOCK_ROWS * LANES

@@ -1,4 +1,6 @@
 import os
+# a CPU-only rehearsal on 512 forced host devices: never claim a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh) combo.
@@ -149,7 +151,7 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool, *,
     compile_s = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = hlo_mod.collective_bytes(compiled.as_text())
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))

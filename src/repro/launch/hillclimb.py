@@ -1,4 +1,6 @@
 import os
+# a CPU-only rehearsal on 512 forced host devices: never claim a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """§Perf hillclimb driver: hypothesis -> change -> re-lower -> confirm/refute.

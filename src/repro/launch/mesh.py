@@ -15,6 +15,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     return compat.make_mesh(shape, axes)
 
 
+def make_cohort_mesh(devices: int):
+    """(devices, 1) over (data, model): one FL client cohort per device."""
+    return compat.make_mesh((devices, 1), ("data", "model"))
+
+
 def make_debug_mesh(devices: int = 8):
     """Small mesh for CPU tests: (devices//4, 4) over (data, model)."""
     assert devices % 4 == 0

@@ -45,7 +45,9 @@ def main():
     from repro.sharding import rules as rules_mod
     from repro.sharding.context import use_sharding_rules
     from repro.utils import compat
+    from repro.utils.cache import init_compile_cache
 
+    init_compile_cache()
     cfg = apply_overrides(get_config(args.arch), tuple(args.overrides))
     model = build_model(cfg)
     n_dev = len(jax.devices())

@@ -47,7 +47,7 @@ def make_train_step(model, config: Config, mesh, *,
     fleet) -> (params, metrics, fleet) — and kind is "fleet_fl_round".
     ``tap`` streams each round's metrics dict out of the shard_map while
     the step executes (see ``make_fl_round``; e.g.
-    ``repro.obs.tap.shard0_sink_tap``); FL kinds only, ``None`` = off.
+    ``repro.obs.tap.step_sink_tap``); FL kinds only, ``None`` = off.
     A tapped FL step takes one extra trailing ``step`` int32 scalar that
     stamps each streamed record with its true round index."""
     if not force_standard:

@@ -49,9 +49,9 @@ Streaming telemetry (``--telemetry-dir`` / ``--telemetry-every``):
   |-----------------------|------------------------------------------------|
   | ``--telemetry-dir D`` | stream one versioned ``train_step`` JSONL      |
   |                       | record per FL round to ``D/telemetry.jsonl``   |
-  |                       | WHILE the step executes (shard-0 ``io_callback``|
-  |                       | tap; see ``repro.obs``).  Off by default — the |
-  |                       | lowered HLO is byte-identical without it.      |
+  |                       | WHILE the step executes (one ``io_callback``   |
+  |                       | per step; see ``repro.obs``).  Off by default: |
+  |                       | the lowered HLO is byte-identical without it.  |
   | ``--telemetry-every N`` | keep every N-th record (default 1 = all)     |
 
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b \
@@ -64,12 +64,20 @@ from __future__ import annotations
 import argparse
 import os
 import time
+from typing import Any, Dict, Optional, Sequence
 
 from repro.config.base import (COLLECTIVE_CHOICES, POWER_POLICIES,  # jax-free
                                SELECTION_POLICIES)
 
 
-def main():
+def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
+    """Run the trainer on ``argv`` (default: the command line).
+
+    Returns ``params`` (the trained model, on the mesh), ``losses`` (one
+    float per step run), ``metrics`` (the last step's metrics dict),
+    ``mesh`` and ``kind`` (the step kind), so callers in the same process
+    can check the run.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--devices", type=int, default=0,
@@ -104,7 +112,7 @@ def main():
     ap.add_argument("--telemetry-every", type=int, default=1,
                     help="keep every N-th telemetry record (default 1)")
     ap.add_argument("overrides", nargs="*")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.devices:
         os.environ["XLA_FLAGS"] = (
@@ -120,12 +128,14 @@ def main():
     from repro.core import fl as fl_mod
     from repro.data.synthetic import token_batch
     from repro.launch import steps as steps_mod
-    from repro.launch.mesh import make_debug_mesh, make_production_mesh
+    from repro.launch.mesh import make_cohort_mesh, make_production_mesh
     from repro.models import build_model
     from repro.sharding import rules as rules_mod
     from repro.sharding.context import use_sharding_rules
     from repro.utils import compat
+    from repro.utils.cache import init_compile_cache
 
+    init_compile_cache()
     overrides = tuple(args.overrides)
     if args.fleet_size:
         overrides += (f"fleet.size={args.fleet_size}",)
@@ -142,10 +152,8 @@ def main():
         mesh = make_production_mesh(multi_pod=True)
     elif n_dev >= 256:
         mesh = make_production_mesh()
-    elif n_dev >= 4:
-        mesh = make_debug_mesh(n_dev - n_dev % 4)
     else:
-        mesh = compat.make_mesh((1, 1), ("data", "model"))
+        mesh = make_cohort_mesh(n_dev)
     print(f"mesh: {dict(mesh.shape)}  arch: {cfg.model.name} "
           f"({cfg.model.param_count()/1e6:.1f}M params)")
 
@@ -159,7 +167,7 @@ def main():
         # records carry the loop's absolute step index via the tapped
         # step's trailing scalar, so a resumed run appending to an
         # existing telemetry.jsonl stays monotonic in true step index
-        tap = obs_tap.shard0_sink_tap(sink, kind="train_step",
+        tap = obs_tap.step_sink_tap(sink, kind="train_step",
                                       every=max(1, args.telemetry_every))
     step_fn, kind = steps_mod.make_train_step(model, cfg, mesh,
                                               collective=collective, tap=tap)
@@ -218,6 +226,8 @@ def main():
                              donate_argnums=(0,))
 
         key = jax.random.PRNGKey(cfg.fl.seed + 1)
+        losses = []
+        metrics = {}
         t0 = time.time()
         for step in range(start, steps):
             key, k_data, k_step = jax.random.split(key, 3)
@@ -229,6 +239,7 @@ def main():
                                                 *step_arg)
             else:
                 params, metrics = jitted(params, batch, k_step, *step_arg)
+            losses.append(metrics["loss"])
             if step % args.log_every == 0:
                 loss = float(metrics["loss"])
                 tok_s = (cfg.train.global_batch * cfg.train.seq_len
@@ -255,6 +266,8 @@ def main():
             jax.block_until_ready(params)   # flush in-flight tap callbacks
             sink.close()
             print(f"telemetry: {sink.emitted} records -> {sink.path}")
+    return {"params": params, "losses": [float(x) for x in losses],
+            "metrics": metrics, "mesh": mesh, "kind": kind}
 
 
 if __name__ == "__main__":

@@ -3,8 +3,8 @@
 Three legs (one module each):
 
 * :mod:`repro.obs.tap` — opt-in ``io_callback`` taps that stream each
-  round's telemetry dict out of the jitted ``lax.scan`` /  ``shard_map``
-  WHILE it executes; ``tap=None`` traces nothing (HLO byte-identical to
+  round's telemetry dict out of the jitted ``lax.scan`` / distributed
+  step WHILE it executes; ``tap=None`` traces nothing (HLO byte-identical to
   a no-obs build).
 * :mod:`repro.obs.sinks` — where records land: ``JsonlSink`` (the
   ``--telemetry-dir`` stream), ``AggregatingSink`` (running mean /
@@ -60,8 +60,8 @@ from repro.obs.sinks import (SCHEMA_VERSION, AggregatingSink, ConsoleSink,
                              JsonlSink, MetricsSink, MultiSink,
                              RecordingSink, make_record, to_jsonable,
                              validate_record)
-from repro.obs.tap import (emit_in_scan, emit_on_shard0, scan_sink_tap,
-                           shard0_sink_tap)
+from repro.obs.tap import (emit_in_scan, emit_step, scan_sink_tap,
+                           step_sink_tap)
 from repro.obs.trace import (FL_PHASES, FLEET_PHASES, WIRE_PHASES,
                              host_span, phase_span)
 
@@ -69,6 +69,6 @@ __all__ = [
     "SCHEMA_VERSION", "AggregatingSink", "ConsoleSink", "JsonlSink",
     "MetricsSink", "MultiSink", "RecordingSink", "make_record",
     "to_jsonable", "validate_record",
-    "emit_in_scan", "emit_on_shard0", "scan_sink_tap", "shard0_sink_tap",
+    "emit_in_scan", "emit_step", "scan_sink_tap", "step_sink_tap",
     "FL_PHASES", "FLEET_PHASES", "WIRE_PHASES", "host_span", "phase_span",
 ]

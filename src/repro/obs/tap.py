@@ -7,44 +7,42 @@ Two traced-side helpers cover the two runtimes:
   ``run_rounds``).  ``ordered=True`` keeps the host callbacks in round
   order, so sinks see round t before round t+1 while the scan is still
   executing later rounds.
-* :func:`emit_on_shard0` — inside a ``shard_map`` body (the distributed
-  ``make_fl_round``).  The callback fires on EVERY shard (that is how
-  ``io_callback`` lowers under fully-manual shard_map on the jax-0.4.37
-  floor), so the traced side passes the flat cohort-shard index along and
-  the HOST adapter filters to shard 0 — one record per round, not one
-  per device.  The ROUND index also rides in the payload (tapped round
-  fns take a trailing replicated ``step`` scalar): the shard callback
-  must stay UNORDERED — an ordered one threads a token through the jit
-  root tuple, which crashes XLA 0.4.37's sharding propagation under
-  ``out_shardings`` — so with async dispatch callbacks from consecutive
-  steps may arrive out of order, and only a payload stamp numbers
-  records correctly (it also makes resumed runs exact: the stamp is the
-  actual step index, not a host-side arrival count).
+* :func:`emit_step` — in the jitted distributed round, AFTER its
+  ``shard_map`` (``make_fl_round``).  The round's metrics leave the
+  shard_map replicated, so the callback sees the whole logical value and
+  runs once per step, on one device: one record per round.  (Inside a
+  partial-auto shard_map — the ``model`` axis left Auto — jax refuses
+  callbacks outright.)  The ROUND index rides in the payload (tapped
+  round fns take a trailing replicated ``step`` scalar): the callback is
+  unordered — jax supports ordered effects on one device only — so with
+  async dispatch callbacks from consecutive steps may arrive out of
+  order, and only a payload stamp numbers records correctly (it also
+  makes resumed runs exact: the stamp is the actual step index, not a
+  host-side arrival count).
 
 Both are strict no-ops when ``tap is None``: nothing is traced, so the
 lowered HLO is byte-identical to a build that never heard of obs (the
 zero-cost-off invariant ``tests/test_obs.py`` pins).
 
-The host adapters (:func:`scan_sink_tap` / :func:`shard0_sink_tap`) turn
+The host adapters (:func:`scan_sink_tap` / :func:`step_sink_tap`) turn
 a :class:`~repro.obs.sinks.MetricsSink` into the host callable the taps
 invoke: each call converts the telemetry pytree (np arrays by the time
 it reaches the host) into one versioned record (``sinks.make_record``)
 and emits it — the scan adapter numbers rounds by counting its ordered
-callbacks, the shard adapter reads the payload's round stamp.
+callbacks, the step adapter reads the payload's round stamp.
 """
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, Optional
 
-import jax
 from jax.experimental import io_callback
 
 from repro.obs import sinks as _sinks
 
 #: a host callable receiving the telemetry dict (np-converted pytree)
 ScanTap = Callable[[Dict[str, Any]], None]
-#: a host callable receiving (telemetry dict, flat shard index, round index)
-ShardTap = Callable[[Dict[str, Any], Any, Any], None]
+#: a host callable receiving (telemetry dict, round index)
+StepTap = Callable[[Dict[str, Any], Any], None]
 
 
 def emit_in_scan(tel: Dict[str, Any], tap: Optional[ScanTap]) -> None:
@@ -58,17 +56,15 @@ def emit_in_scan(tel: Dict[str, Any], tap: Optional[ScanTap]) -> None:
     io_callback(tap, None, tel, ordered=True)
 
 
-def emit_on_shard0(tel: Dict[str, Any], shard_index: jax.Array,
-                   round_index, tap: Optional[ShardTap]) -> None:
-    """Stream one round's metrics from inside a ``shard_map`` body.
+def emit_step(tel: Dict[str, Any], round_index,
+              tap: Optional[StepTap]) -> None:
+    """Stream one distributed round's metrics from the jitted step.
 
-    The callback lowers onto every shard; ``shard_index`` (the flat
-    cohort-shard id the round already computes) rides along so the host
-    adapter keeps only shard 0's copy, and ``round_index`` (the tapped
+    Call it outside the round's ``shard_map``, on its replicated metrics:
+    the callback then runs once per step.  ``round_index`` (the tapped
     round fn's trailing replicated ``step`` scalar) stamps the record —
-    the callback is unordered (an ordered one crashes 0.4.37's sharding
-    propagation under ``out_shardings``), so arrival order cannot number
-    rounds.  ``tap=None`` traces nothing.
+    the callback is unordered, so arrival order cannot number rounds.
+    ``tap=None`` traces nothing.
     """
     if tap is None:
         return
@@ -77,7 +73,7 @@ def emit_on_shard0(tel: Dict[str, Any], shard_index: jax.Array,
             "a tapped distributed round needs its step index: call the "
             "round fn with the trailing `step` scalar so streamed records "
             "carry their true round stamp")
-    io_callback(tap, None, tel, shard_index, round_index, ordered=False)
+    io_callback(tap, None, tel, round_index, ordered=False)
 
 
 def scan_sink_tap(sink: "_sinks.MetricsSink", *, kind: str = "fl_round",
@@ -101,19 +97,16 @@ def scan_sink_tap(sink: "_sinks.MetricsSink", *, kind: str = "fl_round",
     return tap
 
 
-def shard0_sink_tap(sink: "_sinks.MetricsSink", *, kind: str = "fl_round",
-                    every: int = 1) -> ShardTap:
-    """Host adapter for the shard_map tap: drop every shard but 0, then
-    record with the payload's round stamp.  No host-side counter: the
-    unordered shard callback may deliver consecutive steps out of order,
-    so the record's round is the ``round_index`` the traced side shipped
-    (which also keeps a resumed run's appended JSONL stream monotonic in
-    true step index).  ``every`` keeps steps whose ABSOLUTE index is a
-    multiple of ``every``."""
+def step_sink_tap(sink: "_sinks.MetricsSink", *, kind: str = "fl_round",
+                  every: int = 1) -> StepTap:
+    """Host adapter for the distributed round's tap: record with the
+    payload's round stamp.  No host-side counter: the unordered callback
+    may deliver consecutive steps out of order, so the record's round is
+    the ``round_index`` the traced side shipped (which also keeps a resumed
+    run's appended JSONL stream monotonic in true step index).  ``every``
+    keeps steps whose ABSOLUTE index is a multiple of ``every``."""
 
-    def tap(tel: Dict[str, Any], shard_index, round_index) -> None:
-        if int(shard_index) != 0:
-            return
+    def tap(tel: Dict[str, Any], round_index) -> None:
         r = int(round_index)
         if r % every:
             return

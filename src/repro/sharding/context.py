@@ -71,13 +71,6 @@ def current_rules() -> dict:
     return getattr(_state, "rules", None) or DEFAULT_RULES
 
 
-def _manual_axes() -> frozenset:
-    """Axes that are Manual in the current trace (inside shard_map bodies) —
-    with_sharding_constraint may not mention them."""
-    from repro.utils import compat
-    return compat.manual_axes()
-
-
 def shard(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
     """Constrain ``x`` to the resolved sharding of the active mesh (no-op otherwise)."""
     mesh = current_mesh()
@@ -85,7 +78,9 @@ def shard(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
         return x
     if len(logical_axes) != x.ndim:
         raise ValueError(f"{len(logical_axes)} axes for rank-{x.ndim} array")
-    manual = _manual_axes()
+    # axes Manual in the current trace (inside a shard_map body) may not
+    # appear in a sharding constraint
+    manual = jax.sharding.get_abstract_mesh().manual_axes
 
     def resolve(name, size):
         phys = resolve_logical(mesh, name, size)

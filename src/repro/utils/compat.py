@@ -1,139 +1,41 @@
-"""JAX version compatibility shims (pinned floor: jax 0.4.37).
+"""The sharding entry points every mesh/shard_map call site goes through
+(floor: jax 0.9).
 
-The repo targets the modern sharding API (``jax.sharding.AxisType``,
-``jax.set_mesh``, ``jax.shard_map(..., axis_names=...)``); the installed
-0.4.37 predates all three.  Every mesh/shard_map/cost-analysis touchpoint in
-src/, tests/ and benchmarks/ goes through this module so the same code runs
-on both API generations:
-
-  make_mesh(shape, axes)      -> jax.make_mesh, forwarding axis_types only
-                                 when the installed jax understands them
-  set_mesh(mesh)              -> ``jax.set_mesh`` context manager when
-                                 available, else the legacy ``with mesh:``
-                                 resource-env context
-  shard_map(f, mesh, ...)     -> new-style ``axis_names``/``check_vma``;
-                                 partial-auto honoured on jax >= 0.7
-                                 (``HAS_PARTIAL_AUTO``), degraded to
-                                 fully-Manual (replicated body) below, and
-                                 translated to the legacy ``check_rep``
-                                 signature on 0.4.37
-  cost_analysis(compiled)     -> one flat dict (0.4.37 returns a 1-element
-                                 list of dicts)
+  make_mesh(shape, axes)      -> ``jax.make_mesh`` with every axis Auto
+  set_mesh(mesh)              -> ``jax.set_mesh`` (the ambient mesh)
+  shard_map(f, mesh, ...)     -> ``jax.shard_map``; ``axis_names`` is the
+                                 set of Manual axes, the rest stay Auto
+                                 (partial-auto: the ``model`` axis keeps
+                                 tensor parallelism inside the FL round)
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Callable, Dict, Optional, Sequence, Set
 
 import jax
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-
-
-def _version_tuple(v: str):
-    parts = []
-    for p in v.split("."):
-        if not p.isdigit():
-            break
-        parts.append(int(p))
-    return tuple(parts)
-
-
-JAX_VERSION = _version_tuple(jax.__version__)
-# Partial-auto shard_map (some mesh axes Manual, the rest Auto/GSPMD) is
-# what keeps the `model` axis tensor-parallel INSIDE the FL round.  The
-# 0.4.x XLA SPMD partitioner hard-crashes on it for non-trivial bodies
-# (hlo_sharding_util manual-subgroup check), so it is gated to jax >= 0.7
-# where the partitioner handles manual subgroups; below the gate every
-# axis goes Manual and the model axis replicates the body's compute
-# (semantics preserved — see ``shard_map`` below).
-HAS_PARTIAL_AUTO = HAS_NEW_SHARD_MAP and JAX_VERSION >= (0, 7)
+set_mesh = jax.set_mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              axis_types: Optional[Sequence[Any]] = None, devices=None):
-    """``jax.make_mesh`` with ``axis_types`` forwarded only when supported.
-
-    On 0.4.37 every axis behaves as Auto (GSPMD) outside shard_map, which is
-    exactly what the modern call sites request, so dropping the argument is
-    semantics-preserving.
-    """
+              devices=None):
+    """``jax.make_mesh`` with every axis Auto (GSPMD)."""
     kwargs: Dict[str, Any] = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if HAS_AXIS_TYPE and axis_types is not None:
-        kwargs["axis_types"] = axis_types
-    elif HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def auto_axis_types(n: int):
-    """(AxisType.Auto,) * n on new jax, None on old (make_mesh ignores it)."""
-    if HAS_AXIS_TYPE:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    if HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    # legacy: Mesh is itself a context manager (resource env)
-    return mesh
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), **kwargs)
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_vma: bool = True,
               axis_names: Optional[Set[str]] = None) -> Callable:
-    """New-style shard_map signature on either jax generation.
+    """``jax.shard_map``; ``axis_names`` (default: every mesh axis) are
+    Manual inside ``f`` and the remaining axes stay Auto (GSPMD)."""
+    kwargs: Dict[str, Any] = {"mesh": mesh, "in_specs": in_specs,
+                              "out_specs": out_specs, "check_vma": check_vma}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
-    ``axis_names`` is the set of mesh axes that are Manual inside ``f``; the
-    remaining axes stay Auto (GSPMD).  Partial-auto (a strict subset of the
-    mesh axes Manual) is honoured only behind the ``HAS_PARTIAL_AUTO``
-    jax >= 0.7 gate; on older jax the request degrades to fully-Manual —
-    axes absent from in_specs simply replicate the body's compute, so
-    semantics are preserved and tensor parallelism inside the body degrades
-    to replication.  0.4.37 spells fully-Manual through the legacy
-    ``jax.experimental.shard_map`` with ``check_rep`` instead of
-    ``check_vma``.
-    """
-    if HAS_NEW_SHARD_MAP:
-        kwargs: Dict[str, Any] = {"mesh": mesh, "in_specs": in_specs,
-                                  "out_specs": out_specs,
-                                  "check_vma": check_vma}
-        if axis_names is not None:
-            partial = set(axis_names) != set(mesh.axis_names)
-            if not partial or HAS_PARTIAL_AUTO:
-                kwargs["axis_names"] = set(axis_names)
-            # else: drop axis_names -> every axis Manual (the pre-0.7 XLA
-            # SPMD partitioner hard-crashes on manual subgroups)
-        return jax.shard_map(f, **kwargs)
-    # 0.4.37: no new-style API at all; the legacy shard_map with every axis
-    # Manual (partial-auto via auto=... crashes XLA — see HAS_PARTIAL_AUTO)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def manual_axes() -> frozenset:
-    """Mesh axes that are Manual in the current trace (inside shard_map)."""
-    try:  # modern: the abstract mesh records manual axes directly
-        return frozenset(jax.sharding.get_abstract_mesh().manual_axes)
-    except Exception:
-        pass
-    try:  # 0.4.37: every named axis in the axis env is a shard_map axis
-        from jax._src import core as _core
-        return frozenset(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return frozenset()
-
-
-def cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as one flat dict on every jax version."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca

@@ -1,68 +1,40 @@
-"""jax-version compatibility shims (repro.utils.compat).
+"""The sharding entry points (repro.utils.compat) on the installed jax.
 
-The partial-auto shard_map test is version-skipped: it exercises the
-jax >= 0.7 path (``HAS_PARTIAL_AUTO``) where a strict subset of the mesh
-axes goes Manual and the rest stays Auto/GSPMD — the 0.4.x XLA SPMD
-partitioner hard-crashes on manual subgroups, so below the gate compat
-degrades the request to fully-Manual (replicated body), which the
-always-on test covers.  Everything here runs on ONE device (a 1x1 mesh) —
-multi-device behaviour lives in tests/test_distributed.py subprocesses.
+Partial-auto shard_map: a strict subset of the mesh axes goes Manual and
+the rest stays Auto/GSPMD, which keeps the ``model`` axis tensor-parallel
+inside the FL round.  The ring test needs two devices and so runs in a
+subprocess (XLA_FLAGS must be set before jax initialises); everything else
+runs on ONE device (a 1x1 mesh).
 """
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.utils import compat
 
 P = jax.sharding.PartitionSpec
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _psum_over_data(mesh, axis_names):
-    """shard_map'd body reducing over the `data` axis only."""
-    def body(x):
-        return jax.lax.psum(x, ("data",))
-
-    return compat.shard_map(
-        body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-        check_vma=False, axis_names=axis_names)
-
-
-def test_version_gate_consistent_with_installed_jax():
-    """HAS_PARTIAL_AUTO must only ever be set on the new-API jax >= 0.7."""
-    assert compat.JAX_VERSION == compat._version_tuple(jax.__version__)
-    if compat.HAS_PARTIAL_AUTO:
-        assert compat.HAS_NEW_SHARD_MAP and compat.JAX_VERSION >= (0, 7)
-    if compat.JAX_VERSION < (0, 7):
-        assert not compat.HAS_PARTIAL_AUTO
-
-
-def test_partial_request_degrades_to_full_manual_below_gate():
-    """Asking for a Manual subset must WORK on every jax: below the 0.7
-    gate the `model` axis silently joins the Manual set (replicating the
-    body), above it the request passes through — either way the psum over
-    `data` is exact."""
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    x = jnp.arange(8.0)
-    f = jax.jit(_psum_over_data(mesh, axis_names={"data"}))
-    with compat.set_mesh(mesh):
-        np.testing.assert_allclose(np.asarray(f(x)), np.asarray(x))
-
-
-@pytest.mark.skipif(not compat.HAS_PARTIAL_AUTO,
-                    reason="partial-auto shard_map needs jax >= 0.7 "
-                           "(0.4.x XLA crashes on manual subgroups)")
 def test_partial_auto_pipelined_ring_matches_full_manual():
-    """jax >= 0.7 only: the double-buffered ring collective
-    (``pipeline_hops=True``, the default) lowered with the `model` axis
-    left Auto (partial-auto shard_map) must aggregate bit-identically to
-    the fully-Manual lowering — the pipelined ppermute scan must survive
-    the GSPMD partitioner handling the Auto axis around it."""
-    if jax.device_count() < 2:
-        pytest.skip("needs 2 devices for a non-degenerate ring")
+    """The double-buffered ring collective (``pipeline_hops=True``, the
+    default) lowered with the `model` axis left Auto (partial-auto
+    shard_map) must aggregate bit-identically to the fully-Manual
+    lowering — the pipelined ppermute scan must survive the GSPMD
+    partitioner handling the Auto axis around it."""
+    code = """
+    import jax, jax.numpy as jnp, numpy as np
     from repro.config.base import QuantConfig
     from repro.core import aggregation as agg
+    from repro.utils import compat
+    P = jax.sharding.PartitionSpec
 
+    assert jax.device_count() == 2
     mesh = compat.make_mesh((2, 1), ("data", "model"))
     qcfg = QuantConfig(bits=8, use_pallas=True)  # pipeline_hops defaults on
     plan = agg.make_wire_plan("ring", qcfg, ("data",), (2,))
@@ -85,20 +57,27 @@ def test_partial_auto_pipelined_ring_matches_full_manual():
             outs[frozenset(names)] = np.asarray(f(delta, lam, key))
     np.testing.assert_array_equal(outs[frozenset({"data"})],
                                   outs[frozenset({"data", "model"})])
+    print("OK")
+    """
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
+    assert "OK" in r.stdout
 
 
-@pytest.mark.skipif(not compat.HAS_PARTIAL_AUTO,
-                    reason="partial-auto shard_map needs jax >= 0.7 "
-                           "(0.4.x XLA crashes on manual subgroups)")
 def test_partial_auto_keeps_model_axis_auto():
-    """jax >= 0.7 only: with axis_names={'data'} the `model` axis must stay
-    Auto inside the body (manual_axes() == {'data'}) — the tensor-parallel
-    FL-round regime ROADMAP item (c) re-enables."""
+    """With axis_names={'data'} the `model` axis must stay Auto inside the
+    body (its Manual axes are {'data'}) — the tensor-parallel FL-round
+    regime."""
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     seen = {}
 
     def body(x):
-        seen["manual"] = compat.manual_axes()
+        seen["manual"] = frozenset(
+            jax.sharding.get_abstract_mesh().manual_axes)
         return jax.lax.psum(x, ("data",))
 
     f = compat.shard_map(body, mesh=mesh, in_specs=(P("data"),),

@@ -746,8 +746,8 @@ def test_fleet_round_bit_identical_across_collectives():
 def test_fleet_lambda_consistent_across_model_replicas():
     """Regression: the fleet round must index the cohort-shaped λ vector
     (``FleetRoundInfo.lam``, length num_shards) with the DATA-axes-only
-    cohort index.  On the pre-0.7 fully-Manual floor the model axis also
-    replicates the body, and indexing λ with the all-axes flat shard id
+    cohort index.  Where the model axis also runs the body Manual (a
+    fully-Manual shard_map), indexing λ with the all-axes flat shard id
     OOB-clamps the gather — model-axis replicas of one cohort then read
     different λ, so the per-device buffers of outputs that out_specs
     declare replicated (params, survivors) silently diverge

@@ -15,8 +15,12 @@ pytestmark = pytest.mark.slow  # subprocess multi-device lowering, minutes
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_py(code: str, devices: int = 8, timeout: int = 480):
+def run_py(code: str, devices: int = 8, timeout: int = 480,
+           env_extra=None, env_drop=()):
     env = dict(os.environ)
+    for k in env_drop:
+        env.pop(k, None)
+    env.update(env_extra or {})
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -110,9 +114,42 @@ def test_train_driver_runs_a_few_steps():
                 "model.n_layers=2", "model.d_model=128", "model.n_heads=4",
                 "model.n_kv_heads=4", "model.d_ff=256",
                 "model.vocab_size=512",
-                "train.global_batch=8", "train.seq_len=32"]
+                "train.global_batch=24", "train.seq_len=32"]
     from repro.launch.train import main
     main()
     """, timeout=900)
     assert "step kind: fl_round" in out
+    # one client cohort per device, on the data axis
+    assert "mesh: {'data': 8, 'model': 1}" in out
     assert "done: 2 steps" in out
+
+
+def test_compile_cache_follows_env_var(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the cache lands there: the helper
+    leaves the directory to JAX and a compile writes its entry there."""
+    cache = str(tmp_path / "cache")
+    out = run_py(f"""
+    import os, jax, jax.numpy as jnp
+    from repro.utils.cache import CHECKOUT_CACHE_DIR, init_compile_cache
+    assert init_compile_cache() == {cache!r}
+    assert jax.config.jax_compilation_cache_dir == {cache!r}
+    jax.jit(lambda x: jnp.sin(x) * 3)(jnp.arange(64.0)).block_until_ready()
+    print("ENTRIES", len(os.listdir({cache!r})))
+    """, devices=1, env_extra={
+        "JAX_COMPILATION_CACHE_DIR": cache,
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"})
+    assert int(out.split("ENTRIES")[1].split()[0]) >= 1
+
+
+def test_compile_cache_defaults_to_checkout():
+    """Without the variable the cache is the fixed, git-ignored
+    <checkout>/.jax_cache."""
+    want = os.path.join(REPO, ".jax_cache")
+    run_py(f"""
+    import jax
+    from repro.utils.cache import init_compile_cache
+    assert init_compile_cache() == {want!r}
+    assert jax.config.jax_compilation_cache_dir == {want!r}
+    """, devices=1, env_drop=("JAX_COMPILATION_CACHE_DIR",))
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -130,25 +130,15 @@ def test_scan_sink_tap_every_keeps_true_round_indices():
     assert [r["round"] for r in sink.records] == [4, 6, 8]
 
 
-def test_shard0_sink_tap_drops_other_shards():
-    sink = obs_sinks.RecordingSink()
-    tap = obs_tap.shard0_sink_tap(sink, kind="train_step")
-    for shard in (0, 1, 2, 3):     # one round, every shard fires
-        tap({"loss": np.float32(1.0)}, np.int32(shard), np.int32(0))
-    tap({"loss": np.float32(2.0)}, np.int32(0), np.int32(1))
-    assert [r["round"] for r in sink.records] == [0, 1]
-    assert [r["loss"] for r in sink.records] == [1.0, 2.0]
-
-
 def test_shard0_sink_tap_stamps_rounds_from_payload_not_arrival():
-    """The shard tap is an UNORDERED io_callback: consecutive async
+    """The step tap is an UNORDERED io_callback: consecutive async
     steps may arrive out of order, so the record's round must be the
     payload stamp — never a host-side arrival count.  ``every`` keeps
     absolute-index multiples (resume-stable)."""
     sink = obs_sinks.RecordingSink()
-    tap = obs_tap.shard0_sink_tap(sink, kind="train_step", every=2)
+    tap = obs_tap.step_sink_tap(sink, kind="train_step", every=2)
     for r in (4, 3, 2, 6):         # arrival order != step order
-        tap({"loss": np.float32(r)}, np.int32(0), np.int32(r))
+        tap({"loss": np.float32(r)}, np.int32(r))
     assert [r["round"] for r in sink.records] == [4, 2, 6]
     assert [r["loss"] for r in sink.records] == [4.0, 2.0, 6.0]
 
@@ -283,11 +273,11 @@ def test_emit_in_scan_none_is_hlo_byte_identical():
 
 @pytest.mark.slow
 def test_distributed_tap_all_modes_records_match_metrics():
-    """The shard-0 tap under ``make_fl_round``: on the flat (2,4) and
+    """The step tap under ``make_fl_round``: on the flat (2,4) and
     nested (2,2,2) meshes, across all six wire modes, every step streams
-    exactly ONE record (shard filtering works) whose payload bit-matches
-    the step's returned metrics — and the tapped round's params are
-    bit-identical to the untapped build's."""
+    exactly ONE record whose payload bit-matches the step's returned
+    metrics — and the tapped round's params are bit-identical to the
+    untapped build's."""
     code = """
     import dataclasses, jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config, reduced
@@ -309,7 +299,7 @@ def test_distributed_tap_all_modes_records_match_metrics():
         with set_mesh(mesh):
             for mode in ("paper", "int", "packed", "ring", "rsag", "auto"):
                 sink = obs_sinks.RecordingSink()
-                tap = obs_tap.shard0_sink_tap(sink, kind="train_step")
+                tap = obs_tap.step_sink_tap(sink, kind="train_step")
                 f_off = jax.jit(make_fl_round(model, cfg, mesh,
                                               collective=mode))
                 # tapped round fns take a trailing step scalar that
@@ -320,8 +310,8 @@ def test_distributed_tap_all_modes_records_match_metrics():
                 p_on, m_on = f_on(params, batch, jax.random.PRNGKey(2),
                                   jnp.int32(7))
                 jax.block_until_ready(p_on)
-                # exactly one record per step: every shard fired the
-                # callback, the host adapter kept only shard 0
+                # exactly one record per step: the callback sits after
+                # the shard_map, on the replicated metrics
                 assert len(sink.records) == 1, (shape, mode,
                                                 len(sink.records))
                 rec = sink.records[0]

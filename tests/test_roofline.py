@@ -8,7 +8,7 @@ import pytest
 from repro.config import apply_overrides
 from repro.configs import get_config, for_shape, reduced
 from repro.configs.shapes import get_shape
-from repro.utils.compat import cost_analysis, make_mesh
+from repro.utils.compat import make_mesh
 from repro.utils.hlo import collective_bytes
 from repro.utils.roofline import derive_terms, model_flops
 
@@ -43,8 +43,8 @@ def test_xla_scan_undercount_documented():
     ws = jax.ShapeDtypeStruct((8, 256, 256), jnp.float32)
     scan_f = jax.jit(lambda x, w: jax.lax.scan(body, x, w)[0])
     unroll_f = jax.jit(lambda x, w: jax.lax.scan(body, x, w, unroll=True)[0])
-    f_scan = cost_analysis(scan_f.lower(x, ws).compile())["flops"]
-    f_unroll = cost_analysis(unroll_f.lower(x, ws).compile())["flops"]
+    f_scan = scan_f.lower(x, ws).compile().cost_analysis()["flops"]
+    f_unroll = unroll_f.lower(x, ws).compile().cost_analysis()["flops"]
     assert f_unroll >= 7.5 * f_scan, (f_scan, f_unroll)
 
 
@@ -83,7 +83,7 @@ def test_analytic_flops_matches_unrolled_hlo_dense():
         return T._cross_entropy(logits, b["labels"])
 
     g = jax.jit(jax.grad(loss_unrolled))
-    hlo_flops = cost_analysis(g.lower(params, batch).compile())["flops"]
+    hlo_flops = g.lower(params, batch).compile().cost_analysis()["flops"]
 
     mesh = make_mesh((1, 1), ("data", "model"))
     est = analytic_costs(cfg, shape, mesh, step_kind="standard").total_flops
