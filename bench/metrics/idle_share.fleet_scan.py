@@ -1,0 +1,4 @@
+"""Per-layer metric ``idle_share.fleet_scan``: share of the traced window
+in which no op ran on the device.
+"""
+from benchkit.readers import idle_share as read  # noqa: F401

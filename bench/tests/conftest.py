@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU at tiny sizes."""
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+for p in (os.path.join(ROOT, "src"), BENCH, HERE):
+    if p not in sys.path:
+        sys.path.insert(0, p)
