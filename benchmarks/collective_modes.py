@@ -55,11 +55,9 @@ relative, like fleet_scale's budget).
 Runs in a subprocess so the forced device count never leaks into other
 benchmarks (the brief: only the dry-run sees >1 device globally).
 
-For a spans-level view of the 16x16 production mesh, a ``jax.profiler``
-trace of the full dry-run sweep (512 forced host devices, lower+compile)
-is committed at ``experiments/dryrun/profile/`` — regenerate via
-``python -m repro.launch.dryrun --profile-dir experiments/dryrun/profile``
-and open the ``.trace.json.gz`` in Perfetto (see the README next to it).
+For a spans-level view of the 16x16 production mesh, trace the full
+dry-run sweep with ``python -m repro.launch.dryrun --profile-dir DIR``
+and open the trace under ``DIR`` in Perfetto or xprof.
 """
 from __future__ import annotations
 

@@ -19,10 +19,10 @@ prices the callback machinery, not disk I/O.
   ``time_stats`` series are not;
 * a real ``JsonlSink`` sample stream written to a temp dir must yield
   one valid record per round (``sinks.validate_record`` — the schema
-  gate) with bit-exact loss/accuracy vs the returned history;
-* the committed span-coverage artifact passes
-  ``profile_summary.check()`` (>= 80%% of provenanced collective device
-  time attributed to the wire-phase spans).
+  gate) with bit-exact loss/accuracy vs the returned history.
+
+Span coverage is not gated here: the benchmark's trace reduction
+(``bench/benchkit/trace_reduce.py``) reports it from a chip trace.
 """
 from __future__ import annotations
 
@@ -141,8 +141,7 @@ def run() -> None:
 
 
 def check() -> int:
-    """The three obs gates (see the module docstring); returns failures."""
-    from benchmarks import profile_summary
+    """The two obs gates (see the module docstring); returns failures."""
     from repro.obs import sinks as obs_sinks
     from repro.obs import tap as obs_tap
 
@@ -176,8 +175,6 @@ def check() -> int:
     print(f"  obs_records: {len(lines)} jsonl records, {bad} schema "
           f"errors, history bit-match={match} "
           f"[{'ok' if ok else 'STREAM INVALID'}]")
-    # 3) the committed span-coverage artifact
-    failures += profile_summary.check()
     return failures
 
 

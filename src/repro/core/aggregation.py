@@ -386,28 +386,29 @@ def _exec_paper(plan: WirePlan, delta, alpha, lam, key) -> PyTree:
 def _flat_codes(plan: WirePlan, xs: List[jax.Array],
                 keys: jax.Array) -> jax.Array:
     """Quantize every (weighted, scaled) leaf with its own PRNG stream and
-    concatenate — the codes every quantized mode agrees on bit-for-bit.
-    ``use_pallas`` routes through the quantize kernel (same key -> same
-    rounding noise -> same codes as the pure path)."""
+    flatten end to end (``quant.flat_buffer``) — the codes every quantized
+    mode agrees on bit-for-bit.  ``use_pallas`` routes through the
+    quantize kernel (same key -> same rounding noise -> same codes as the
+    pure path)."""
     qcfg = plan.quant
     if qcfg.use_pallas:
         from repro.kernels import ops as kops
-        return jnp.concatenate([
+        return quant.flat_buffer([
             kops.stochastic_quantize_codes(
-                x, k, qcfg.bits, clip=qcfg.clip,
-                stochastic=qcfg.stochastic).reshape(-1)
+                x, k, qcfg.bits, clip=qcfg.clip, stochastic=qcfg.stochastic)
             for x, k in zip(xs, keys)])
-    return jnp.concatenate([
+    return quant.flat_buffer([
         quant.quantize_codes(x, k, qcfg.bits, clip=qcfg.clip,
-                             stochastic=qcfg.stochastic).reshape(-1)
+                             stochastic=qcfg.stochastic)
         for x, k in zip(xs, keys)])
 
 
 def _flat_noise(xs: List[jax.Array], keys: jax.Array) -> jax.Array:
-    """The concatenated per-leaf rounding-noise streams (what the fused
-    quantize+pack kernels consume so their codes match the pure path)."""
-    return jnp.concatenate([
-        jax.random.uniform(k, x.shape, dtype=jnp.float32).reshape(-1)
+    """The per-leaf rounding-noise streams, flattened end to end (what the
+    fused quantize+pack kernels consume so their codes match the pure
+    path)."""
+    return quant.flat_buffer([
+        jax.random.uniform(k, x.shape, dtype=jnp.float32)
         for x, k in zip(xs, keys)])
 
 
@@ -436,7 +437,7 @@ def _reduce_packed(plan: WirePlan, xs, keys, n: int) -> jax.Array:
     if qcfg.use_pallas:
         from repro.kernels import ops as kops
         with obs_trace.phase_span("wire/quantize_pack"):
-            xcat = jnp.concatenate([x.reshape(-1) for x in xs])
+            xcat = quant.flat_buffer(xs)
             words = kops.quantize_pack(xcat, None, qcfg.bits, clip=qcfg.clip,
                                        lane_bits=lane,
                                        stochastic=qcfg.stochastic,
@@ -487,7 +488,7 @@ def _reduce_ring(plan: WirePlan, xs, keys, n: int) -> jax.Array:
     with obs_trace.phase_span("wire/quantize_pack"):
         if qcfg.use_pallas:
             from repro.kernels import ops as kops
-            xcat = jnp.concatenate([x.reshape(-1) for x in xs])
+            xcat = quant.flat_buffer(xs)
             if qcfg.pipeline_hops:
                 # fused front-end: buf and acc in ONE megakernel pass
                 words, chunks = kops.quantize_pack_chunk(
@@ -600,7 +601,7 @@ def _rsag_level(plan: WirePlan, codes: jax.Array, axis: str, K: int,
     if front is not None:
         front_words, chunks = front
     else:
-        chunks = jnp.pad(codes, (0, K * C - n)).reshape(K, C)
+        chunks = quant.flat_buffer([codes], K * C).reshape(K, C)
     idx = jax.lax.axis_index(axis)
     perm = [(j, (j + 1) % K) for j in range(K)]
 
@@ -772,7 +773,7 @@ def _reduce_rsag(plan: WirePlan, xs, keys, n: int) -> jax.Array:
             from repro.kernels import ops as kops
             lane0 = quant.packed_lane_bits(qcfg.bits, 1)
             front = kops.quantize_pack_chunk(
-                jnp.concatenate([x.reshape(-1) for x in xs]), None,
+                quant.flat_buffer(xs), None,
                 qcfg.bits, clip=qcfg.clip, lane_bits=lane0,
                 stochastic=qcfg.stochastic, num_chunks=active[0][1],
                 bias=quant.lane_bias(lane0), u=_flat_noise(xs, keys))

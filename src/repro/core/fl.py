@@ -196,17 +196,19 @@ class FLSimulator:
         fl = self.config.fl
         K = fl.devices_per_round
         rngs = jax.random.split(rng, K + 1)
-        deltas, losses, accs = jax.vmap(
-            lambda b, r: self._client_update(params, b, r)
-        )(stacked_batches, rngs[:K])
+        with obs_trace.phase_span("sim/client_update"):
+            deltas, losses, accs = jax.vmap(
+                lambda b, r: self._client_update(params, b, r)
+            )(stacked_batches, rngs[:K])
 
         lam = ch.sample_packet_success(rngs[K], (K,),
                                        self.config.channel.error_prob)
-        if fl.error_aware:
-            new_params = agg.error_aware_aggregate(params, deltas,
-                                                   client_alphas, lam)
-        else:
-            new_params = agg.naive_aggregate(params, deltas, lam)
+        with obs_trace.phase_span("sim/aggregate"):
+            if fl.error_aware:
+                new_params = agg.error_aware_aggregate(params, deltas,
+                                                       client_alphas, lam)
+            else:
+                new_params = agg.naive_aggregate(params, deltas, lam)
         return new_params, losses.mean(), accs.mean(), lam.sum()
 
     def _fleet_round(self, params, fleet, k_round, stacked_batches,
@@ -223,29 +225,33 @@ class FLSimulator:
         fleet, info = pop_fleet.round_update(fleet, k_fleet, cfg,
                                              self.num_params, K)
 
-        deltas, losses, accs = jax.vmap(
-            lambda b, r: self._client_update(params, b, r)
-        )(stacked_batches, jax.random.split(k_cli, K))
+        with obs_trace.phase_span("sim/client_update"):
+            deltas, losses, accs = jax.vmap(
+                lambda b, r: self._client_update(params, b, r)
+            )(stacked_batches, jax.random.split(k_cli, K))
 
-        if cfg.fleet.error_reweight:
-            new_params = pop_errors.reweighted_aggregate(
-                params, deltas, client_alphas, info.valid, info.lam,
-                cfg.channel.error_prob, rates=info.rates_sel,
-                min_rate=pop_power.min_rate(cfg, self.num_params))
-        elif cfg.fl.error_aware:
-            new_params = agg.error_aware_aggregate(
-                params, deltas, client_alphas * info.valid, info.lam)
-        else:
-            new_params = agg.naive_aggregate(params, deltas, info.lam)
+        with obs_trace.phase_span("sim/aggregate"):
+            if cfg.fleet.error_reweight:
+                new_params = pop_errors.reweighted_aggregate(
+                    params, deltas, client_alphas, info.valid, info.lam,
+                    cfg.channel.error_prob, rates=info.rates_sel,
+                    min_rate=pop_power.min_rate(cfg, self.num_params))
+            elif cfg.fl.error_aware:
+                new_params = agg.error_aware_aggregate(
+                    params, deltas, client_alphas * info.valid, info.lam)
+            else:
+                new_params = agg.naive_aggregate(params, deltas, info.lam)
 
-        tau = jnp.max(info.valid * pop_fleet.round_latency_s(
-            cfg, info.rates_sel, self.num_params, self.macs))
-        tel = pop_tel.simulator_round_telemetry(
-            loss=losses.mean(), accuracy=accs.mean(), selected=info.idx,
-            valid=info.valid, lam=info.lam, battery_j=fleet.battery_j,
-            charge_j=info.charge_j, tau_s=tau, power_w=fleet.p_last,
-            outage_sel=info.outage_sel, cost_sel=info.cost_sel,
-            harvest_j=info.harvest_j, error_prob=cfg.channel.error_prob)
+        with obs_trace.phase_span("sim/telemetry"):
+            tau = jnp.max(info.valid * pop_fleet.round_latency_s(
+                cfg, info.rates_sel, self.num_params, self.macs))
+            tel = pop_tel.simulator_round_telemetry(
+                loss=losses.mean(), accuracy=accs.mean(), selected=info.idx,
+                valid=info.valid, lam=info.lam, battery_j=fleet.battery_j,
+                charge_j=info.charge_j, tau_s=tau, power_w=fleet.p_last,
+                outage_sel=info.outage_sel, cost_sel=info.cost_sel,
+                harvest_j=info.harvest_j,
+                error_prob=cfg.channel.error_prob)
         return new_params, fleet, tel
 
     def _tap_dispatch(self, tel):
@@ -293,24 +299,28 @@ class FLSimulator:
         availability, selection, drops, battery debit — runs inside the
         scan with no host round-trips (the 10^6-device workload).  ``tap``
         (a host callable taking the round telemetry dict) streams every
-        round out of the scan while it runs (``repro.obs.tap``)."""
+        round out of the scan while it runs (``repro.obs.tap``).  The host
+        stages carry the ``obs.trace.SIM_HOST_SPANS`` spans."""
         per_round = []
         rng_in = rng
         for _ in range(rounds):
             rng, k = jax.random.split(rng)
             stacked, alphas, _ = self._round_inputs(k)
             per_round.append((stacked, alphas))
-        xs = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
-                                    *per_round)
+        with obs_trace.host_span("sim/stack"):
+            xs = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
+                                        *per_round)
         carry = (params, self.fleet_state,
                  jax.random.fold_in(rng_in, _FLEET_STREAM))
         scan_fn = self._fleet_scan_fn(eval_fn, tap is not None)
         self._active_tap = tap
         try:
-            (params, fleet, _), tels = scan_fn(carry, xs)
+            with obs_trace.host_span("sim/scan"):
+                (params, fleet, _), tels = scan_fn(carry, xs)
             self.fleet_state = fleet
             # materializes (blocks), so every in-scan callback has fired
-            history = pop_tel.expand_history(tels, rounds, start_round)
+            with obs_trace.host_span("sim/history"):
+                history = pop_tel.expand_history(tels, rounds, start_round)
         finally:
             self._active_tap = None
         if return_rng:
@@ -323,25 +333,34 @@ class FLSimulator:
         """Host-side per-round prep: client sampling + minibatch stacking.
 
         Returns (stacked_batches with (K, I, B, ...) leaves, client_alphas,
-        k_run) — the exact inputs of the jitted ``_round``.
+        k_run) — the exact inputs of the jitted ``_round``.  The one
+        blocking read is the client choice (``sim/sync``).
         """
-        fl = self.config.fl
-        k_sel, k_data, k_run = jax.random.split(rng, 3)
-        clients = np.asarray(jax.random.choice(
-            k_sel, self.store.num_clients, (fl.devices_per_round,),
-            replace=False))
-        batch_size = self.config.train.global_batch
-        batches = []
-        for i, c in enumerate(clients):
-            ks = jax.random.split(jax.random.fold_in(k_data, i), fl.local_iters)
-            batches.append([self.store.client_batch(k, int(c), batch_size)
-                            for k in ks])
-        stacked = jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves),
-            *[jax.tree_util.tree_map(lambda *l: jnp.stack(l), *bs)
-              for bs in batches])
-        client_alphas = self.alphas[jnp.asarray(clients)]
-        return stacked, client_alphas, k_run
+        with obs_trace.host_span("sim/round_inputs"):
+            fl = self.config.fl
+            k_sel, k_data, k_run = jax.random.split(rng, 3)
+            choice = jax.random.choice(
+                k_sel, self.store.num_clients, (fl.devices_per_round,),
+                replace=False)
+            with obs_trace.host_span("sim/sync"):
+                clients = np.asarray(choice)
+            batch_size = self.config.train.global_batch
+            batches = []
+            for i, c in enumerate(clients):
+                ks = jax.random.split(jax.random.fold_in(k_data, i),
+                                      fl.local_iters)
+                bs = []
+                for k in ks:
+                    with obs_trace.host_span("sim/client_batch"):
+                        bs.append(self.store.client_batch(k, int(c),
+                                                          batch_size))
+                batches.append(bs)
+            stacked = jax.tree_util.tree_map(
+                lambda *leaves: jnp.stack(leaves),
+                *[jax.tree_util.tree_map(lambda *l: jnp.stack(l), *bs)
+                  for bs in batches])
+            client_alphas = self.alphas[jnp.asarray(clients)]
+            return stacked, client_alphas, k_run
 
     def run_round(self, params, rng) -> Tuple[PyTree, RoundTelemetry]:
         if self.fleet_state is not None:
@@ -423,18 +442,24 @@ class FLSimulator:
         for _ in range(rounds):
             rng, k = jax.random.split(rng)
             per_round.append(self._round_inputs(k))
-        xs = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
-                                    *per_round)
+        with obs_trace.host_span("sim/stack"):
+            xs = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
+                                        *per_round)
         scan_fn = self._scan_fn(eval_fn, tap is not None)
         self._active_tap = tap
         try:
-            params, (losses, metrics, survs) = scan_fn(params, xs)
-            e, tau = self.round_energy()
-            history = [{"round": start_round + t, "loss": float(losses[t]),
-                        "accuracy": float(metrics[t]),
-                        "survivors": int(survs[t]), "energy_j": e,
-                        "tau_s": tau}
-                       for t in range(rounds)]
+            with obs_trace.host_span("sim/scan"):
+                params, stats = scan_fn(params, xs)
+            with obs_trace.host_span("sim/history"):
+                with obs_trace.host_span("sim/sync"):
+                    losses, metrics, survs = jax.device_get(stats)
+                e, tau = self.round_energy()
+                history = [{"round": start_round + t,
+                            "loss": float(losses[t]),
+                            "accuracy": float(metrics[t]),
+                            "survivors": int(survs[t]), "energy_j": e,
+                            "tau_s": tau}
+                           for t in range(rounds)]
         finally:
             self._active_tap = None
         if return_rng:
@@ -461,6 +486,8 @@ class FLSimulator:
             local_iters=cfg.fl.local_iters, macs_per_iter=self.macs,
             rates_per_device=rate, num_devices=cfg.fl.num_devices,
             devices_per_round=cfg.fl.devices_per_round)
+        with obs_trace.host_span("sim/sync"):
+            e, tau = jax.device_get((e, tau))
         return float(e), float(tau)
 
     def train(self, params, rounds: int, rng, *, target_accuracy: float = 0.0,

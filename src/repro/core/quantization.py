@@ -151,6 +151,37 @@ def packed_words(n: int, bits: int, *, lane_bits: int = 0) -> int:
     return -(-int(n) // codes_per_word(bits, lane_bits=lane_bits))
 
 
+def flat_buffer(pieces: Sequence[jax.Array],
+                size: int | None = None) -> jax.Array:
+    """The pieces flattened end to end into one vector of ``size`` elements
+    (default: their total; a larger size leaves a zero tail).
+
+    Equal bit for bit to ``jnp.pad(jnp.concatenate(...), ...)``, but each
+    piece, and the zero tail, is written with ``lax.dynamic_update_slice``
+    at a static offset into an uninitialised buffer (``lax.empty``, which
+    the TPU compiler allocates without writing, as it does for a
+    concatenate).  The TPU compiler lowers a concatenate of these sizes to
+    a chain of buffer writes that carry no ``op_name``; these writes keep
+    the caller's ``phase_span``, so the device time of the wire's flat
+    buffers is attributed to its phase.
+    """
+    flat = [p.reshape(-1) for p in pieces]
+    total = sum(p.size for p in flat)
+    size = total if size is None else int(size)
+    if size < total:
+        raise ValueError(f"flat_buffer: {total} elements exceed size {size}")
+    dtype = jnp.result_type(*flat) if flat else jnp.float32
+    flat = [p.astype(dtype) for p in flat if p.size]
+    if size > total:
+        flat.append(jnp.zeros((size - total,), dtype))
+    buf = jax.lax.empty((size,), dtype)
+    offset = 0
+    for p in flat:
+        buf = jax.lax.dynamic_update_slice(buf, p, (offset,))
+        offset += p.size
+    return buf
+
+
 def pack_codes(codes: jax.Array, bits: int, *, lane_bits: int = 0,
                sum_of: int = 1, bias: int | None = None) -> jax.Array:
     """Pack int32 codes in [-G, G-1] into a flat uint32 word vector.
@@ -174,7 +205,7 @@ def pack_codes(codes: jax.Array, bits: int, *, lane_bits: int = 0,
     # modular uint32 add: exact for every lane width up to the full 32 bits
     # (an int32 add would overflow for biases >= 2^31)
     biased = codes.reshape(-1).astype(jnp.uint32) + jnp.uint32(b)
-    biased = jnp.pad(biased, (0, cpw * W - n)).reshape(cpw, W)
+    biased = flat_buffer([biased], cpw * W).reshape(cpw, W)
     shifts = (jnp.arange(cpw, dtype=jnp.uint32) * lane)[:, None]
     return jnp.sum(biased << shifts, axis=0, dtype=jnp.uint32)
 

@@ -32,8 +32,7 @@ rounds (schema: ``repro.obs``).
 ``--profile-dir`` wraps the whole session in ``jax.profiler.trace``: the
 trace/lower/compile work on the forced-device mesh lands as an xplane
 artifact under ``DIR/plugins/profile/<ts>/`` (open with TensorBoard or
-xprof).  The committed 16x16 dry-run trace referenced by the benchmark
-docs lives under ``experiments/dryrun/profile/`` (see tests/README.md).
+xprof).
 """
 
 import argparse

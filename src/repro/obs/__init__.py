@@ -12,8 +12,10 @@ Three legs (one module each):
   ``MultiSink`` fan-out, ``RecordingSink`` (tests).
 * :mod:`repro.obs.trace` — ``phase_span`` / ``host_span`` named spans
   (select -> power-assign -> quantize-pack-chunk -> per-hop collective
-  -> unpack-dequant -> apply) that ``benchmarks/profile_summary.py``
-  joins with a ``jax.profiler`` trace into per-phase device time.
+  -> unpack-dequant -> apply, the simulator's client update / aggregate
+  and its host input path) that the benchmark's trace reduction
+  (``bench/benchkit/trace_reduce.py``) joins with a ``jax.profiler``
+  trace into per-phase device time and named host stages.
 
 Telemetry record schema (version ``sinks.SCHEMA_VERSION`` = 1)
 --------------------------------------------------------------
@@ -62,13 +64,14 @@ from repro.obs.sinks import (SCHEMA_VERSION, AggregatingSink, ConsoleSink,
                              validate_record)
 from repro.obs.tap import (emit_in_scan, emit_step, scan_sink_tap,
                            step_sink_tap)
-from repro.obs.trace import (FL_PHASES, FLEET_PHASES, WIRE_PHASES,
-                             host_span, phase_span)
+from repro.obs.trace import (FL_PHASES, FLEET_PHASES, SIM_HOST_SPANS,
+                             SIM_PHASES, WIRE_PHASES, host_span, phase_span)
 
 __all__ = [
     "SCHEMA_VERSION", "AggregatingSink", "ConsoleSink", "JsonlSink",
     "MetricsSink", "MultiSink", "RecordingSink", "make_record",
     "to_jsonable", "validate_record",
     "emit_in_scan", "emit_step", "scan_sink_tap", "step_sink_tap",
-    "FL_PHASES", "FLEET_PHASES", "WIRE_PHASES", "host_span", "phase_span",
+    "FL_PHASES", "FLEET_PHASES", "SIM_HOST_SPANS", "SIM_PHASES",
+    "WIRE_PHASES", "host_span", "phase_span",
 ]

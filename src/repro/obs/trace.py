@@ -1,19 +1,26 @@
-"""Phase-attributed tracing: named spans over the wire/fleet phases.
+"""Phase-attributed tracing: named spans over the wire/fleet/simulator
+phases.
 
 :func:`phase_span` wraps ``jax.named_scope`` — inside a jitted function
 it lands the span path in every enclosed HLO op's
-``metadata={op_name="jit(f)/.../<span>/<op>"}``, which is what
-``benchmarks/profile_summary.py`` joins against a ``jax.profiler`` trace
-(whose device events carry only the post-fusion ``hlo_op`` names) to
-attribute device time per phase.  :func:`host_span` wraps
-``jax.profiler.TraceAnnotation`` for host-side (un-jitted) sections.
+``metadata={op_name="jit(f)/.../<span>/<op>"}``, which is what the
+benchmark's trace reduction (``bench/benchkit/trace_reduce.py``) joins
+against a ``jax.profiler`` trace (whose device events carry only the
+post-fusion HLO op names) to attribute device time per phase.
+:func:`host_span` wraps ``jax.profiler.TraceAnnotation`` for host-side
+(un-jitted) sections: it writes a named slice into the profiler's own
+trace, on the device trace's clock, so an idle gap of the device can be
+put down to the host stage that covers it.  With no profiler attached a
+host span costs one TraceMe check.
 
 Span names are hierarchical ``area/phase`` strings; the canonical wire
 phases (mirroring ``telemetry.wire_phase_split``'s keys) are in
 :data:`WIRE_PHASES`, the fleet state-machine phases in
-:data:`FLEET_PHASES`.  Nested spans concatenate
-(``wire/quantize_pack/pallas/quantize_pack_chunk``) — the profile
-summary attributes an op to the OUTERMOST known phase on its path.
+:data:`FLEET_PHASES`, the simulator round's own device phases in
+:data:`SIM_PHASES` and its host stages in :data:`SIM_HOST_SPANS`.
+Nested spans concatenate (``wire/quantize_pack/pallas/quantize_pack_chunk``)
+— the reduction attributes an op to the OUTERMOST known phase on its
+path.
 """
 from __future__ import annotations
 
@@ -41,6 +48,24 @@ FLEET_PHASES = (
 
 #: the FL round phases outside the wire/fleet areas
 FL_PHASES = ("fl/local_steps", "fl/apply")
+
+#: the fleet simulator round's device phases (``core.fl.FLSimulator``);
+#: siblings of the fleet/* phases, never enclosing them
+SIM_PHASES = (
+    "sim/client_update",     # vmapped local steps, delta, quantization
+    "sim/aggregate",         # error-aware / naive / reweighted aggregate
+    "sim/telemetry",         # round telemetry (fleet-wide quantiles)
+)
+
+#: the simulator's host stages (``host_span``), per ``run_rounds`` call
+SIM_HOST_SPANS = (
+    "sim/round_inputs",      # one round's client choice + batch draws
+    "sim/client_batch",      # one eager client minibatch draw
+    "sim/stack",             # stacking a chunk's inputs
+    "sim/scan",              # dispatching the jitted round scan
+    "sim/history",           # expanding the stacked telemetry
+    "sim/sync",              # one blocking device -> host read
+)
 
 
 def phase_span(name: str):

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aggregation as agg
+from repro.obs import trace as obs_trace
 
 PyTree = Any
 
@@ -161,8 +162,12 @@ def expand_history(stacked: Dict[str, jax.Array], rounds: int,
     ``accuracy`` is the ONE canonical metric key: the scan body overwrites
     it in place when an ``eval_fn`` is folded in (no shadow ``metric``
     alias), so streamed tap records and this expansion read the same key.
+    Each key is one blocking device -> host read (``sim/sync``).
     """
-    host = {k: np.asarray(v) for k, v in stacked.items()}
+    host = {}
+    for k, v in stacked.items():
+        with obs_trace.host_span("sim/sync"):
+            host[k] = np.asarray(v)
     history = []
     for t in range(rounds):
         h: Dict[str, Any] = {"round": start_round + t,

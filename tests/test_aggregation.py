@@ -197,3 +197,68 @@ def test_aggregate_kernel_matches_pure():
     want = kref.masked_aggregate_ref(upd, alphas * lam)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# the wire's flat buffers (quantization.flat_buffer)
+# ---------------------------------------------------------------------------
+
+def _tree(kind):
+    """Leaf lists for the flat-buffer tests: empty, odd-sized, many-leaf."""
+    key = jax.random.PRNGKey(7)
+    if kind == "empty":
+        return []
+    if kind == "zero_size_leaf":
+        return [jnp.zeros((0, 3)), jnp.arange(5.0), jnp.zeros((0,))]
+    if kind == "odd":
+        return [jax.random.normal(key, (3, 7)), jnp.arange(11.0),
+                jax.random.normal(key, (1, 1, 5))]
+    return [jax.random.normal(jax.random.fold_in(key, i), (i % 4 + 1, 3))
+            for i in range(40)]
+
+
+@pytest.mark.parametrize("kind", ["empty", "zero_size_leaf", "odd", "many"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32, jnp.uint32])
+@pytest.mark.parametrize("pad", [0, 1, 13])
+def test_flat_buffer_equals_concatenate_and_pad(kind, dtype, pad):
+    """The buffer writes are exact: bit for bit the concatenation of the
+    flattened pieces, zero-padded to ``size``."""
+    from repro.core import quantization as quant
+    pieces = [(p * 1000).astype(dtype) for p in _tree(kind)]
+    total = sum(p.size for p in pieces)
+    if not pieces:  # nothing to concatenate: a zero vector of the size
+        got = quant.flat_buffer(pieces, pad)
+        assert got.shape == (pad,) and not np.asarray(got).any()
+        return
+    want = jnp.pad(jnp.concatenate([p.reshape(-1) for p in pieces]),
+                   (0, pad))
+    got = jax.jit(lambda ps: quant.flat_buffer(ps, total + pad))(pieces)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(  # default size: the concatenation
+        np.asarray(quant.flat_buffer(pieces)),
+        np.asarray(jnp.concatenate([p.reshape(-1) for p in pieces])))
+    with pytest.raises(ValueError):
+        quant.flat_buffer(pieces + [jnp.ones((2,), dtype)], total)
+
+
+@pytest.mark.parametrize("n", [1, 97, 4096, 4099])
+@pytest.mark.parametrize("bits,lane,sum_of", [(8, 8, 1), (8, 10, 4),
+                                              (2, 3, 2), (5, 5, 1)])
+def test_pack_codes_unchanged_by_the_flat_buffer(n, bits, lane, sum_of):
+    """``pack_codes`` writes its word planes through ``flat_buffer``; the
+    words equal the jnp.pad + reshape layout it replaced."""
+    from repro.core import quantization as quant
+    g = 2 ** (bits - 1)
+    codes = jax.random.randint(jax.random.PRNGKey(n), (n,), -g * sum_of,
+                               (g - 1) * sum_of + 1, jnp.int32)
+    cpw = quant.codes_per_word(bits, lane_bits=lane)
+    W = quant.packed_words(n, bits, lane_bits=lane)
+    biased = codes.astype(jnp.uint32) + jnp.uint32(g * sum_of)
+    planes = jnp.pad(biased, (0, cpw * W - n)).reshape(cpw, W)
+    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * lane)[:, None]
+    want = jnp.sum(planes << shifts, axis=0, dtype=jnp.uint32)
+    got = quant.pack_codes(codes, bits, lane_bits=lane, sum_of=sum_of)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    back = quant.unpack_codes(got, bits, n, lane_bits=lane, sum_of=sum_of)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(codes))

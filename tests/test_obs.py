@@ -340,3 +340,91 @@ def test_distributed_tap_all_modes_records_match_metrics():
                        capture_output=True, text=True, env=env, timeout=900)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     assert "OK" in r.stdout
+
+
+# ---------------------------------------------------------------------------
+# the simulator's host spans (obs.trace.host_span) and its sync counter
+# ---------------------------------------------------------------------------
+
+def _sim_host_span_counts(trace_dir):
+    """name -> count of the sim/* host spans in a profiler trace, read
+    back with ``jax.profiler.ProfileData``."""
+    import collections
+    import glob
+    from jax.profiler import ProfileData
+    pbs = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                    recursive=True)
+    assert pbs, "the profiler wrote no trace"
+    counts = collections.Counter()
+    for plane in ProfileData.from_file(sorted(pbs)[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("sim/"):
+                    counts[event.name] += 1
+    return counts
+
+
+@pytest.mark.parametrize("fleet_size", [64, 0])
+def test_run_rounds_host_spans_count_rounds_and_syncs(tmp_path, fleet_size):
+    """Under ``jax.profiler.trace``, ``run_rounds`` over 2 chunks records
+    one ``sim/round_inputs`` a round, one ``sim/client_batch`` per client
+    minibatch draw, one ``sim/stack``/``sim/scan``/``sim/history`` a
+    chunk, and one ``sim/sync`` per blocking device -> host read: the
+    client choice of each round, plus per chunk either one read per
+    stacked telemetry key (fleet) or the scan's stats and the round
+    energy (legacy)."""
+    from repro.obs import trace as obs_trace
+    from repro.population import telemetry as pop_tel
+    model, sim = _sim(fleet_size=fleet_size)
+    params = model.init(jax.random.PRNGKey(1))
+    chunk, chunks = 2, 2
+    params, _ = sim.run_rounds(params, chunk, jax.random.PRNGKey(5))
+    with jax.profiler.trace(str(tmp_path)):
+        for c in range(chunks):
+            params, _ = sim.run_rounds(params, chunk,
+                                       jax.random.PRNGKey(10 + c))
+    counts = _sim_host_span_counts(str(tmp_path))
+    assert set(counts) <= set(obs_trace.SIM_HOST_SPANS)
+    rounds = chunk * chunks
+    fl = sim.config.fl
+    assert counts["sim/round_inputs"] == rounds
+    assert counts["sim/client_batch"] == (rounds * fl.devices_per_round
+                                          * fl.local_iters)
+    for name in ("sim/stack", "sim/scan", "sim/history"):
+        assert counts[name] == chunks, name
+    # fleet: simulator_round_telemetry's 7 round keys and the fleet extras
+    per_chunk = (7 + len(pop_tel.FLEET_METRIC_KEYS)) if fleet_size else 2
+    assert counts["sim/sync"] == rounds + chunks * per_chunk
+
+
+@pytest.mark.parametrize("fleet_size", [64, 0])
+def test_run_rounds_unchanged_by_host_spans(tmp_path, monkeypatch,
+                                            fleet_size):
+    """The host spans observe and change nothing: params and history of
+    ``run_rounds`` bit-match with the spans on (profiler attached or
+    not) and with every ``host_span`` replaced by a no-op."""
+    import contextlib
+
+    from repro.obs import trace as obs_trace
+    model, sim = _sim(fleet_size=fleet_size)
+    params = model.init(jax.random.PRNGKey(1))
+    fleet0 = sim.fleet_state
+
+    def run():
+        sim.fleet_state = fleet0
+        return sim.run_rounds(params, 3, jax.random.PRNGKey(2))
+
+    p_on, h_on = run()
+    with jax.profiler.trace(str(tmp_path)):
+        p_traced, h_traced = run()
+    monkeypatch.setattr(obs_trace, "host_span",
+                        lambda name: contextlib.nullcontext())
+    p_off, h_off = run()
+    assert h_on == h_traced == h_off
+    for a, b, c in zip(jax.tree_util.tree_leaves(p_on),
+                       jax.tree_util.tree_leaves(p_traced),
+                       jax.tree_util.tree_leaves(p_off)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
