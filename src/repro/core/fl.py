@@ -150,6 +150,16 @@ class FLSimulator:
             np.prod(s.shape) for s in jax.tree_util.tree_leaves(
                 jax.eval_shape(model.init, jax.random.PRNGKey(0)))))
         self.macs = macs_per_iter or config.energy.macs_per_iteration
+        # the batched input path: a (num_clients, L) partition table exists
+        # only where every partition has one length L, because a compiled
+        # draw without replacement needs L static; a ragged store keeps the
+        # per-draw path of _round_inputs (see _chunk_inputs)
+        sizes = {len(p) for p in client_store.partitions}
+        self._part_table = (
+            jnp.asarray(np.stack(client_store.partitions), jnp.int32)
+            if len(sizes) == 1 else None)
+        self._chunk_inputs_fn = jax.jit(self._draw_chunk,
+                                        static_argnames="rounds")
         self._round_fn = jax.jit(self._round)
         self._scan_fns: Dict[Any, Callable] = {}
         self._fleet_scan_fns: Dict[Any, Callable] = {}
@@ -294,24 +304,19 @@ class FLSimulator:
                           return_rng: bool, tap: Optional[Callable] = None):
         """Fleet-mode multi-round driver: ONE jitted ``lax.scan`` whose
         carry threads (params, FleetState, per-round key).  The data side
-        (client minibatch stacking) is prepared before the scan exactly as
-        in the legacy path; every per-round fleet update — fading,
-        availability, selection, drops, battery debit — runs inside the
-        scan with no host round-trips (the 10^6-device workload).  ``tap``
-        (a host callable taking the round telemetry dict) streams every
-        round out of the scan while it runs (``repro.obs.tap``).  The host
-        stages carry the ``obs.trace.SIM_HOST_SPANS`` spans."""
-        per_round = []
-        rng_in = rng
-        for _ in range(rounds):
-            rng, k = jax.random.split(rng)
-            stacked, alphas, _ = self._round_inputs(k)
-            per_round.append((stacked, alphas))
-        with obs_trace.host_span("sim/stack"):
-            xs = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
-                                        *per_round)
+        (client choice and minibatches) is prepared before the scan by
+        :meth:`_chunk_inputs`, as in the legacy path: one compiled call
+        for a rectangular store, per-draw for a ragged one.  Every
+        per-round fleet update — fading, availability, selection, drops,
+        battery debit — runs inside the scan with no host round-trips
+        (the 10^6-device workload).  ``tap`` (a host callable taking the
+        round telemetry dict) streams every round out of the scan while
+        it runs (``repro.obs.tap``).  The host stages carry the
+        ``obs.trace.SIM_HOST_SPANS`` spans."""
+        (batches, alphas, _), rng_out = self._chunk_inputs(rng, rounds)
+        xs = (batches, alphas)
         carry = (params, self.fleet_state,
-                 jax.random.fold_in(rng_in, _FLEET_STREAM))
+                 jax.random.fold_in(rng, _FLEET_STREAM))
         scan_fn = self._fleet_scan_fn(eval_fn, tap is not None)
         self._active_tap = tap
         try:
@@ -324,8 +329,68 @@ class FLSimulator:
         finally:
             self._active_tap = None
         if return_rng:
-            return params, history, rng
+            return params, history, rng_out
         return params, history
+
+    def _draw_chunk(self, data, table, alphas, rng, *, rounds: int):
+        """Compiled body of :meth:`_chunk_inputs` on a rectangular store:
+        the draws of ``rounds`` :meth:`_round_inputs` calls, key for key,
+        vmapped over rounds, client slots and local steps.  ``data`` (the
+        store), ``table`` (the (num_clients, L) partitions) and ``alphas``
+        are arguments, so the store is never folded into the program."""
+        fl = self.config.fl
+        batch_size = self.config.train.global_batch
+        replace = table.shape[1] < batch_size
+
+        def next_key(rng, _):
+            rng, k = jax.random.split(rng)
+            return rng, k
+
+        rng, round_keys = jax.lax.scan(next_key, rng, None, length=rounds)
+
+        def client_batches(k_data, i, client):
+            part = table[client]
+            idx = jax.vmap(lambda k: jax.random.choice(
+                k, part, (batch_size,), replace=replace))(
+                    jax.random.split(jax.random.fold_in(k_data, i),
+                                     fl.local_iters))
+            return {name: v[idx] for name, v in data.items()}
+
+        def round_inputs(k):
+            k_sel, k_data, k_run = jax.random.split(k, 3)
+            clients = jax.random.choice(k_sel, table.shape[0],
+                                        (fl.devices_per_round,),
+                                        replace=False)
+            stacked = jax.vmap(functools.partial(client_batches, k_data))(
+                jnp.arange(fl.devices_per_round), clients)
+            return stacked, alphas[clients], k_run
+
+        return jax.vmap(round_inputs)(round_keys), rng
+
+    def _chunk_inputs(self, rng, rounds: int):
+        """The scan inputs of ``rounds`` rounds and the advanced ``rng``:
+        ``rounds`` :meth:`_round_inputs` over the chain (rng, k =
+        split(rng)), stacked on a leading rounds axis, bit for bit.
+
+        A rectangular store (every partition of one length, as
+        ``partition_iid`` gives when the samples divide evenly) draws the
+        whole chunk in ONE compiled call (``sim/chunk_inputs``), with no
+        read back to the host.  A ragged store takes the per-draw path:
+        ``rounds`` eager :meth:`_round_inputs` calls, then ``sim/stack``.
+        """
+        if self._part_table is not None:
+            with obs_trace.host_span("sim/chunk_inputs"):
+                return self._chunk_inputs_fn(self.store.data,
+                                             self._part_table, self.alphas,
+                                             rng, rounds=rounds)
+        per_round = []
+        for _ in range(rounds):
+            rng, k = jax.random.split(rng)
+            per_round.append(self._round_inputs(k))
+        with obs_trace.host_span("sim/stack"):
+            xs = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
+                                        *per_round)
+        return xs, rng
 
     # -- public API -------------------------------------------------------------
 
@@ -334,7 +399,10 @@ class FLSimulator:
 
         Returns (stacked_batches with (K, I, B, ...) leaves, client_alphas,
         k_run) — the exact inputs of the jitted ``_round``.  The one
-        blocking read is the client choice (``sim/sync``).
+        blocking read is the client choice (``sim/sync``).  This is the
+        per-draw path: ``run_round`` (legacy) and a ragged store's
+        :meth:`_chunk_inputs` take it; a rectangular store's chunks are
+        drawn in one compiled call that gives the same bits.
         """
         with obs_trace.host_span("sim/round_inputs"):
             fl = self.config.fl
@@ -415,6 +483,9 @@ class FLSimulator:
         of ``rounds``.  Telemetry comes back stacked and is expanded into
         the same per-round history dicts ``train`` produces; ``eval_fn``
         (a jit-able params -> scalar metric) is folded into the scan body.
+        The chunk's inputs come from :meth:`_chunk_inputs`: one compiled
+        call where every partition of the store has one length, the
+        per-draw :meth:`_round_inputs` loop where the store is ragged.
 
         Fleet mode (``config.fleet.enabled``) dispatches to the fleet
         scan instead: (params, FleetState, per-round key) in the carry,
@@ -438,13 +509,7 @@ class FLSimulator:
                                           eval_fn=eval_fn,
                                           start_round=start_round,
                                           return_rng=return_rng, tap=tap)
-        per_round = []
-        for _ in range(rounds):
-            rng, k = jax.random.split(rng)
-            per_round.append(self._round_inputs(k))
-        with obs_trace.host_span("sim/stack"):
-            xs = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
-                                        *per_round)
+        xs, rng = self._chunk_inputs(rng, rounds)
         scan_fn = self._scan_fn(eval_fn, tap is not None)
         self._active_tap = tap
         try:

@@ -57,11 +57,16 @@ SIM_PHASES = (
     "sim/telemetry",         # round telemetry (fleet-wide quantiles)
 )
 
-#: the simulator's host stages (``host_span``), per ``run_rounds`` call
+#: the simulator's host stages (``host_span``), per ``run_rounds`` call.
+#: A rectangular store (every partition of one length) draws a chunk's
+#: inputs in one compiled call, ``sim/chunk_inputs``; a ragged store takes
+#: the per-draw path, ``sim/round_inputs`` with its ``sim/client_batch``
+#: draws a round, then ``sim/stack``
 SIM_HOST_SPANS = (
-    "sim/round_inputs",      # one round's client choice + batch draws
-    "sim/client_batch",      # one eager client minibatch draw
-    "sim/stack",             # stacking a chunk's inputs
+    "sim/chunk_inputs",      # a chunk's inputs in one compiled call
+    "sim/round_inputs",      # per-draw: one round's choice + batch draws
+    "sim/client_batch",      # per-draw: one eager client minibatch draw
+    "sim/stack",             # per-draw: stacking a chunk's inputs
     "sim/scan",              # dispatching the jitted round scan
     "sim/history",           # expanding the stacked telemetry
     "sim/sync",              # one blocking device -> host read

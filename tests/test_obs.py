@@ -34,7 +34,7 @@ from repro.obs import tap as obs_tap
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _sim(fleet_size=0):
+def _sim(fleet_size=0, num_samples=300):
     cfg = get_config("mnist_cnn")
     cfg = dataclasses.replace(
         cfg,
@@ -43,8 +43,8 @@ def _sim(fleet_size=0):
         train=dataclasses.replace(cfg.train, global_batch=16),
         fleet=dataclasses.replace(cfg.fleet, size=fleet_size))
     model = build_model(cfg)
-    store = make_federated_digits(jax.random.PRNGKey(0), num_samples=300,
-                                  num_clients=8)
+    store = make_federated_digits(jax.random.PRNGKey(0),
+                                  num_samples=num_samples, num_clients=8)
     return model, FLSimulator(model, cfg, store)
 
 
@@ -367,17 +367,23 @@ def _sim_host_span_counts(trace_dir):
 
 
 @pytest.mark.parametrize("fleet_size", [64, 0])
-def test_run_rounds_host_spans_count_rounds_and_syncs(tmp_path, fleet_size):
+@pytest.mark.parametrize("num_samples", [320, 300],
+                         ids=["rectangular", "ragged"])
+def test_run_rounds_host_spans_count_rounds_and_syncs(tmp_path, fleet_size,
+                                                      num_samples):
     """Under ``jax.profiler.trace``, ``run_rounds`` over 2 chunks records
-    one ``sim/round_inputs`` a round, one ``sim/client_batch`` per client
-    minibatch draw, one ``sim/stack``/``sim/scan``/``sim/history`` a
-    chunk, and one ``sim/sync`` per blocking device -> host read: the
-    client choice of each round, plus per chunk either one read per
-    stacked telemetry key (fleet) or the scan's stats and the round
-    energy (legacy)."""
+    one ``sim/scan``/``sim/history`` a chunk and one ``sim/sync`` per
+    blocking device -> host read: per chunk either one read per stacked
+    telemetry key (fleet) or the scan's stats and the round energy
+    (legacy).  A rectangular store (8 partitions of 40) draws each
+    chunk's inputs in one ``sim/chunk_inputs``, with no client choice
+    read back; a ragged one (partitions of 38 and 37) takes the per-draw
+    path: one ``sim/round_inputs`` a round with its choice's
+    ``sim/sync``, one ``sim/client_batch`` per client minibatch draw and
+    one ``sim/stack`` a chunk."""
     from repro.obs import trace as obs_trace
     from repro.population import telemetry as pop_tel
-    model, sim = _sim(fleet_size=fleet_size)
+    model, sim = _sim(fleet_size=fleet_size, num_samples=num_samples)
     params = model.init(jax.random.PRNGKey(1))
     chunk, chunks = 2, 2
     params, _ = sim.run_rounds(params, chunk, jax.random.PRNGKey(5))
@@ -389,14 +395,22 @@ def test_run_rounds_host_spans_count_rounds_and_syncs(tmp_path, fleet_size):
     assert set(counts) <= set(obs_trace.SIM_HOST_SPANS)
     rounds = chunk * chunks
     fl = sim.config.fl
-    assert counts["sim/round_inputs"] == rounds
-    assert counts["sim/client_batch"] == (rounds * fl.devices_per_round
-                                          * fl.local_iters)
-    for name in ("sim/stack", "sim/scan", "sim/history"):
+    for name in ("sim/scan", "sim/history"):
         assert counts[name] == chunks, name
     # fleet: simulator_round_telemetry's 7 round keys and the fleet extras
     per_chunk = (7 + len(pop_tel.FLEET_METRIC_KEYS)) if fleet_size else 2
-    assert counts["sim/sync"] == rounds + chunks * per_chunk
+    if num_samples == 320:
+        assert counts["sim/chunk_inputs"] == chunks
+        for name in ("sim/round_inputs", "sim/client_batch", "sim/stack"):
+            assert counts[name] == 0, name
+        assert counts["sim/sync"] == chunks * per_chunk
+    else:
+        assert counts["sim/chunk_inputs"] == 0
+        assert counts["sim/round_inputs"] == rounds
+        assert counts["sim/client_batch"] == (rounds * fl.devices_per_round
+                                              * fl.local_iters)
+        assert counts["sim/stack"] == chunks
+        assert counts["sim/sync"] == rounds + chunks * per_chunk
 
 
 @pytest.mark.parametrize("fleet_size", [64, 0])

@@ -17,7 +17,7 @@ from repro.data.pipeline import make_federated_digits
 from repro.models import build_model
 
 
-def _sim(**over):
+def _sim(num_samples=400, **over):
     cfg = get_config("mnist_cnn")
     cfg = dataclasses.replace(
         cfg,
@@ -25,8 +25,8 @@ def _sim(**over):
                                learning_rate=0.05),
         train=dataclasses.replace(cfg.train, global_batch=16), **over)
     model = build_model(cfg)
-    store = make_federated_digits(jax.random.PRNGKey(0), num_samples=400,
-                                  num_clients=8)
+    store = make_federated_digits(jax.random.PRNGKey(0),
+                                  num_samples=num_samples, num_clients=8)
     return model, FLSimulator(model, cfg, store)
 
 
@@ -125,3 +125,76 @@ def test_train_early_stop_round_granular():
     _, hist = sim.train(params, 5, jax.random.PRNGKey(10),
                         target_accuracy=1e-6)
     assert len(hist) == 1
+
+
+# ---------------------------------------------------------------------------
+# a chunk's inputs: one compiled draw (rectangular store) vs per-draw
+# ---------------------------------------------------------------------------
+
+def _round_inputs_loop(sim, rng, rounds):
+    """The per-draw reference: ``rounds`` ``_round_inputs`` over the
+    chain (rng, k = split(rng)), stacked on a leading rounds axis."""
+    per_round = []
+    for _ in range(rounds):
+        rng, k = jax.random.split(rng)
+        per_round.append(sim._round_inputs(k))
+    return jax.tree_util.tree_map(lambda *l: jnp.stack(l), *per_round), rng
+
+
+def _assert_trees_equal(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        assert (x.shape, x.dtype) == (y.shape, y.dtype)
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("rounds", [1, 3])
+@pytest.mark.parametrize("num_samples", [400, 80],
+                         ids=["part_ge_batch", "part_lt_batch"])
+def test_chunk_inputs_bit_equal_round_inputs_loop(num_samples, rounds):
+    """On a rectangular store the compiled chunk draw gives the per-draw
+    loop's batches, alphas, run keys and advanced rng bit for bit, with
+    partitions of 50 >= B = 16 (drawn without replacement) and of
+    10 < B (drawn with replacement)."""
+    _, sim = _sim(num_samples)
+    assert sim._part_table is not None
+    rng = jax.random.PRNGKey(2 ** 31 + 11)
+    _assert_trees_equal(sim._chunk_inputs(rng, rounds),
+                        _round_inputs_loop(sim, rng, rounds))
+
+
+@pytest.mark.parametrize("fleet_size", [64, 0])
+def test_run_rounds_batched_inputs_match_per_draw_path(fleet_size):
+    """``run_rounds`` on a rectangular store (one compiled draw a chunk)
+    gives the params, history and rng of the per-draw path, in fleet
+    mode and in legacy mode."""
+    cfg = get_config("mnist_cnn")
+    model, sim = _sim(fleet=dataclasses.replace(cfg.fleet, size=fleet_size))
+    assert sim._part_table is not None
+    params = model.init(jax.random.PRNGKey(11))
+    fleet0 = sim.fleet_state
+
+    def run():
+        sim.fleet_state = fleet0
+        return sim.run_rounds(params, 2, jax.random.PRNGKey(12),
+                              return_rng=True)
+
+    p_batched, h_batched, r_batched = run()
+    sim._part_table = None  # what a ragged store gets: the per-draw path
+    p_draw, h_draw, r_draw = run()
+    _assert_trees_equal((p_batched, r_batched), (p_draw, r_draw))
+    assert h_batched == h_draw
+
+
+def test_ragged_store_takes_per_draw_path_and_matches_loop():
+    """A ragged store (300 samples over 8 clients: partitions of 38 and
+    37) keeps the per-draw path, and ``run_rounds`` still reproduces the
+    per-round loop."""
+    model, sim = _sim(300)
+    assert sim._part_table is None
+    params = model.init(jax.random.PRNGKey(13))
+    p_loop, tels = _loop(sim, params, 2, jax.random.PRNGKey(14))
+    p_scan, hist = sim.run_rounds(params, 2, jax.random.PRNGKey(14))
+    _assert_trees_equal(p_loop, p_scan)
+    assert [h["survivors"] for h in hist] == [t.survivors for t in tels]
