@@ -76,10 +76,19 @@ class CompileCounter:
             self.cache_loads += 1
 
 
+def model_value(cfg, key: str) -> Any:
+    """The program's model configuration at ``key``; a dotted key reads a
+    nested group (``moe.num_experts``)."""
+    value = cfg.model
+    for part in key.split("."):
+        value = getattr(value, part)
+    return value
+
+
 def check_model(cfg, sizes: Dict[str, Any]) -> None:
     """The program's model configuration must be what the file states."""
-    wrong = {k: (getattr(cfg.model, k), v) for k, v in sizes.items()
-             if getattr(cfg.model, k) != v}
+    wrong = {k: (model_value(cfg, k), v) for k, v in sizes.items()
+             if model_value(cfg, k) != v}
     if wrong:
         raise ValueError(f"configuration file and program differ "
                          f"(program, file): {wrong}")

@@ -7,7 +7,7 @@ import importlib
 import shutil
 import sys
 import tempfile
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Iterable
 
 from benchkit import common
 
@@ -47,7 +47,11 @@ def check_devices(chips: int, require_tpu: bool):
 
 def run_cell(cell: common.Cell, seed: int, seconds: float, trace: bool,
              readers: Dict[str, Callable], *, t_process: float,
-             require_tpu: bool = True) -> Dict[str, Any]:
+             require_tpu: bool = True, spans: Iterable[str] = (),
+             host: Iterable[str] = ()) -> Dict[str, Any]:
+    """One run; ``spans`` and ``host`` are the device and host spans the
+    readers declare.  The cell's driver module may add host spans of its
+    own (``HOST_SPANS``), by which the breakdown names idle gaps."""
     devices = check_devices(cell.chips, require_tpu)
     import jax
     from repro.utils.cache import init_compile_cache
@@ -67,7 +71,9 @@ def run_cell(cell: common.Cell, seed: int, seconds: float, trace: bool,
                                "device": rec["device"]}
         if trace:
             from benchkit import trace_reduce
-            ctx = trace_reduce.reduce(tracer.dir, rec, devices)
+            host = tuple(dict.fromkeys(
+                (*host, *getattr(drv, "HOST_SPANS", ()))))
+            ctx = trace_reduce.reduce(tracer.dir, rec, devices, spans, host)
             print(f"bench: trace attribution coverage "
                   f"{ctx['coverage']:.4f} of device op time "
                   f"({ctx['provenanced']:.4f} carries an op_name)",

@@ -7,21 +7,46 @@ one jitted call from the seed, and drives that one compiled step through
 its first rounds, which the plain reference later repeats.  The window
 then dispatches rounds back to back from the same state, as the trainer's
 loop does, keeping one round queued behind the one it waits for, and ends
-when every dispatched round is done.
+when every dispatched round is done.  Once the window has closed, the
+mean of each of the step's scalar metrics over the window's rounds becomes
+a counter for the per-layer readers.
+
+The architecture is the configuration file's: its ``reference`` key names
+a module under ``bench/benchkit/`` that provides :data:`ARCH_API`, the
+plain reference of the round and the yardstick's counts.  A new
+architecture comes as such a module and a configuration file that names
+it.
 """
 from __future__ import annotations
 
+import importlib
 import math
 import sys
+from types import ModuleType
 from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from benchkit import common, data, ref_olmo, work
+from benchkit import common, data, work
 
 #: rounds driven in set-up and repeated by the reference
 CHECK_ROUNDS = 3
+
+#: what an architecture module provides: ``dims(cfg)`` the sizes it reads
+#: from the program's configuration; ``param_count(dims)``;
+#: ``round_flops(dims, tokens, seq_len)`` the model FLOPs of a round's
+#: local steps; ``run_rounds(start, rounds, dims, fl, dot, dtype, fault)``
+#: the plain reference; ``f32_dot`` and ``bf16_dot`` its products in the
+#: configuration's precision and in the control's
+ARCH_API = ("dims", "param_count", "round_flops", "run_rounds", "f32_dot",
+            "bf16_dot")
+
+
+def arch(cell: common.Cell) -> ModuleType:
+    """The architecture module the cell's configuration file names."""
+    return importlib.import_module("benchkit." + cell.config["reference"])
 
 
 def build_config(cell: common.Cell):
@@ -39,14 +64,6 @@ def build_config(cell: common.Cell):
     return cfg
 
 
-def model_dims(cfg) -> Dict[str, Any]:
-    m = cfg.model
-    return {"d_model": m.d_model, "n_heads": m.n_heads,
-            "n_kv_heads": m.n_kv_heads, "d_ff": m.d_ff,
-            "vocab_size": m.vocab_size, "n_layers": m.n_layers,
-            "rope_theta": m.rope_theta}
-
-
 class Program:
     """The system under test, built as the trainer builds it."""
 
@@ -56,6 +73,7 @@ class Program:
         from repro.sharding import rules as rules_mod
         from repro.utils import compat
         self.cell = cell
+        self.arch = arch(cell)
         self.cfg = build_config(cell)
         self.model = build_model(self.cfg)
         self.mesh = compat.make_mesh((len(devices), 1), ("data", "model"),
@@ -134,7 +152,7 @@ def run(cell: common.Cell, seed: int, seconds: float, devices, counter,
         counter.armed = True
         if tracer is not None:
             tracer.start()
-        losses = []
+        scalars = []
         pending = None
         t0 = common.now()
         with jax.profiler.TraceAnnotation("bench/window"):
@@ -143,7 +161,9 @@ def run(cell: common.Cell, seed: int, seconds: float, devices, counter,
                     key, batch, k_step = prog.inputs(key)
                 with jax.profiler.TraceAnnotation("bench/dispatch"):
                     params, metrics = prog.step(params, batch, k_step)
-                losses.append(metrics["loss"])
+                scalars.append({k: v for k, v in metrics.items()
+                                if not isinstance(v, dict)
+                                and np.ndim(v) == 0})
                 if pending is not None:
                     with jax.profiler.TraceAnnotation("bench/wait"):
                         pending.block_until_ready()
@@ -156,17 +176,20 @@ def run(cell: common.Cell, seed: int, seconds: float, devices, counter,
         if tracer is not None:
             tracer.stop()
         counter.armed = False
-        host_losses = [float(l) for l in losses]
+        scalars = jax.device_get(scalars)
+        host_losses = [float(m["loss"]) for m in scalars]
 
         record = {
             "work": round_work(prog),
             "hlo": (hlo_texts([(prog.step, (params, batch, k_step)),
                                (prog.inputs, (key,))])
                     if tracer is not None else {}),
-            "setup_s": setup_s, "window_s": t1 - t0, "rounds": len(losses),
-            "attempted": len(losses),
+            "setup_s": setup_s, "window_s": t1 - t0,
+            "rounds": len(scalars), "attempted": len(scalars),
             "failed": sum(not math.isfinite(l) for l in host_losses),
-            "metrics": {"round_s": (t1 - t0) / len(losses)},
+            "metrics": {"round_s": (t1 - t0) / len(scalars)},
+            "counters": {k: float(np.mean([m[k] for m in scalars]))
+                         for k in scalars[0]},
             "device": common.device_info(devices),
             "prog": prog_read,
         }
@@ -197,13 +220,14 @@ def resolved_mode(mode: str, bits: int, cohorts: int) -> str:
 def round_work(prog: Program) -> Dict[str, float]:
     """FLOPs of a round's local steps and the least bytes of its wire."""
     t, K = prog.cell.traffic, len(prog.mesh.devices.flat)
-    dims = model_dims(prog.cfg)
-    d = work.olmo_param_count(dims)
+    a = prog.arch
+    dims = a.dims(prog.cfg)
+    d = a.param_count(dims)
     bits = work.wire_bits_per_param(
         resolved_mode(t["collective"], t["bits"], K), t["bits"], K)
     wire = work.wire_interface_bytes(
         d, jnp.dtype(prog.cfg.model.dtype).itemsize, bits, K)
-    return {"flops_per_round": work.olmo_round_flops(
+    return {"flops_per_round": a.round_flops(
                 dims, t["global_batch"] * t["seq_len"], t["seq_len"]),
             "wire_bits_per_param": bits, "wire_hbm_bytes": wire["hbm"],
             "wire_ici_bytes": wire["ici"]}
@@ -212,11 +236,12 @@ def round_work(prog: Program) -> Dict[str, float]:
 def reference(cell: common.Cell, seed: int, control: bool = False,
               fault: str = "") -> Dict[str, Any]:
     """The plain reference over the first CHECK_ROUNDS rounds, on one
-    device, from the same seed.  ``control`` runs it in bfloat16, the
-    precision below the configuration's float32; ``fault`` as in
-    ``ref_olmo.run_rounds``."""
+    device, from the same seed: the architecture module's ``run_rounds``.
+    ``control`` runs it in bfloat16, the precision below the
+    configuration's float32; ``fault`` as in ``run_rounds``."""
+    a = arch(cell)
     cfg = build_config(cell)
-    dims = model_dims(cfg)
+    dims = a.dims(cfg)
     t = cell.traffic
     from repro.models import build_model
     shapes = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
@@ -224,7 +249,7 @@ def reference(cell: common.Cell, seed: int, control: bool = False,
     weights = jax.jit(lambda k: data.make_weights(k, shapes))
     rounds = []
     inputs = jax.jit(lambda k: data.round_inputs(
-        k, t["global_batch"], t["seq_len"], dims["vocab_size"]))
+        k, t["global_batch"], t["seq_len"], cfg.model.vocab_size))
     for _ in range(CHECK_ROUNDS):
         key, batch, k_step = inputs(key)
         rounds.append((batch, k_step))
@@ -232,12 +257,12 @@ def reference(cell: common.Cell, seed: int, control: bool = False,
           "bits": t["bits"], "clip": cfg.quant.clip,
           "error_prob": t["error_prob"], "lr": cfg.fl.learning_rate}
     start = weights(k_w)
-    dot, dtype = ref_olmo.f32_dot, jnp.float32
+    dot, dtype = a.f32_dot, jnp.float32
     if control:
         start = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16),
                                        start)
-        dot, dtype = ref_olmo.bf16_dot, jnp.bfloat16
-    params, losses, survivors = ref_olmo.run_rounds(
+        dot, dtype = a.bf16_dot, jnp.bfloat16
+    params, losses, survivors = a.run_rounds(
         start, rounds, dims, fl, dot, dtype, fault)
     norms = jax.jit(_change_norms)(params, weights(k_w))
     return {"loss": losses, "survivors": survivors,

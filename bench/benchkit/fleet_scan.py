@@ -18,6 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchkit import common, data, ref_fleet, work
+# the simulator's host stages, by which a traced run's breakdown names the
+# device's idle gaps
+from repro.obs.trace import SIM_HOST_SPANS as HOST_SPANS
 
 #: the fleet vectors compared after the first chunk
 FLEET_FIELDS = ("h_re", "h_im", "battery_j", "p_last", "available")
@@ -91,7 +94,6 @@ def run(cell: common.Cell, seed: int, seconds: float, devices, counter,
 
     counter.armed = True
     if tracer is not None:
-        _annotate_host_path(sim)
         tracer.start()
     chunks, failed = 0, 0
     t0 = common.now()
@@ -124,22 +126,6 @@ def run(cell: common.Cell, seed: int, seconds: float, devices, counter,
         "device": common.device_info(devices),
         "prog": prog_read,
     }
-
-
-def _annotate_host_path(sim) -> None:
-    """Traced runs only: host spans around the simulator's input
-    preparation and history expansion, so that the trace can name the
-    device's idle gaps by them."""
-    from repro.population import telemetry
-
-    def spanned(name, fn):
-        def call(*a, **kw):
-            with jax.profiler.TraceAnnotation(name):
-                return fn(*a, **kw)
-        return call
-    sim._round_inputs = spanned("bench/input_prep", sim._round_inputs)
-    telemetry.expand_history = spanned("bench/history",
-                                       telemetry.expand_history)
 
 
 def scan_hlo(sim, params, key, chunk: int) -> Dict[str, str]:

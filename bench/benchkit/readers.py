@@ -1,6 +1,8 @@
 """The arithmetic of the per-layer metrics.  Each metric's reader under
 ``bench/metrics/`` binds one of these; each returns ``None`` where the
-trace holds nothing for it to read."""
+trace holds nothing for it to read.  A reader that reads a span at any
+depth, or a host span, declares it in its module (``SPANS``,
+``HOST_SPANS``) and passes the same names here."""
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
@@ -15,16 +17,38 @@ FLEET = ("fleet/advance_channel", "fleet/power_assign", "fleet/rates_cost",
          "fleet/select", "fleet/drop_realize", "fleet/energy_ledger")
 
 
-def span_seconds_per_round(ctx: Dict[str, Any], spans) -> Optional[float]:
-    hit = [ctx["span_s"][s] for s in spans if s in ctx["span_s"]]
+def _per_round(ctx, table: Dict[str, float], names) -> Optional[float]:
+    hit = [table[n] for n in names if n in table]
     if not hit or not ctx["rounds"]:
         return None
     return sum(hit) / ctx["rounds"]
 
 
+def span_seconds_per_round(ctx: Dict[str, Any], spans) -> Optional[float]:
+    return _per_round(ctx, ctx["span_s"], spans)
+
+
 def span_ms(ctx, spans) -> Optional[float]:
     s = span_seconds_per_round(ctx, spans)
     return None if s is None else s * 1e3
+
+
+def span_in_ms(ctx, spans) -> Optional[float]:
+    """Device ms per round of the ops under declared ``spans``, at any
+    depth of their path."""
+    s = _per_round(ctx, ctx["span_in_s"], spans)
+    return None if s is None else s * 1e3
+
+
+def host_span_ms(ctx, names) -> Optional[float]:
+    """Host ms per round inside the window of declared host spans."""
+    s = _per_round(ctx, ctx["host_span_s"], names)
+    return None if s is None else s * 1e3
+
+
+def host_span_count(ctx, names) -> Optional[float]:
+    """Declared host spans per round inside the window."""
+    return _per_round(ctx, ctx["host_span_n"], names)
 
 
 def local_steps_ms(ctx):

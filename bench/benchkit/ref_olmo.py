@@ -18,6 +18,10 @@ to: per cohort ``c`` the stream ``fold_in(step_key, c)``, the packet draw
 ``uniform(fold_in(stream, 11)) >= q``, the quantizer's noise from
 ``split(fold_in(stream, 13), leaves)``, one key per parameter leaf in
 tree order.
+
+It is the OLMo architecture module of ``fl_round.ARCH_API``: with the
+reference it provides the sizes it reads (:func:`dims`) and the
+yardstick's counts, ``work.olmo_param_count`` and ``work.olmo_round_flops``.
 """
 from __future__ import annotations
 
@@ -27,9 +31,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchkit.work import olmo_param_count as param_count  # noqa: F401
+from benchkit.work import olmo_round_flops as round_flops  # noqa: F401
+
 PyTree = Any
 HIGHEST = jax.lax.Precision.HIGHEST
 EPS = 1e-12
+
+
+def dims(cfg) -> Dict[str, Any]:
+    """The sizes of the configuration that the reference and the counts
+    read."""
+    m = cfg.model
+    return {"d_model": m.d_model, "n_heads": m.n_heads,
+            "n_kv_heads": m.n_kv_heads, "d_ff": m.d_ff,
+            "vocab_size": m.vocab_size, "n_layers": m.n_layers,
+            "rope_theta": m.rope_theta}
 
 
 def f32_dot(spec: str, a, b):

@@ -1,14 +1,26 @@
 """A profiler trace of the measured window -> per-span device time, busy
-and idle time, and the breakdown of the result line.
+and idle time, host stages, and the breakdown of the result line.
 
 Spans are the program's ``obs.trace.phase_span`` names (``jax.named_scope``),
 which reach the compiled HLO as ``op_name`` metadata.  The join that turns
-a device op into its span is a copy of ``benchmarks/profile_summary.py``:
+a device op into its layer:
 
   device op event (module, hlo op name, duration)
       -> the compiled program's HLO text, ``%<op> = ... op_name="<path>"``
-      -> the OUTERMOST known span on <path>
-      -> per-span device time, and the share of op time so attributed.
+      -> the OUTERMOST of :data:`SPANS` on <path>
+      -> per-span device time (``span_s``), and the share of op time so
+         attributed (``coverage``).
+
+Each op counts toward one layer only, so ``span_s`` over :data:`SPANS` never
+counts an op twice.  A per-layer reader may also declare spans of its own
+(``SPANS`` in its module under ``bench/metrics/``), at any depth: a declared
+span is a part of a layer, and ``span_in_s`` gives it the device time of
+every op whose path holds it, nested or not, without moving ``span_s``.
+A reader's ``HOST_SPANS`` name host stages (``obs.trace.host_span``, a
+``jax.profiler.TraceAnnotation``): ``host_span_s`` and ``host_span_n`` give
+their time and count inside ``bench/window``, and the idle gaps of the
+breakdown are named by them too.  ``counters`` passes through the
+driver's means of the program's own per-round metrics over the window.
 """
 from __future__ import annotations
 
@@ -31,13 +43,22 @@ _METADATA_RE = re.compile(
 
 
 # ---------------------------------------------------------------------------
-# the join (copied from benchmarks/profile_summary.py)
+# the join
 # ---------------------------------------------------------------------------
 
 def parse_hlo_op_names(hlo_text: str) -> Dict[str, str]:
     """``as_text()`` -> {instruction name: op_name metadata path}."""
     return {m.group(1): m.group(2)
             for m in _METADATA_RE.finditer(hlo_text)}
+
+
+def span_at(path: str, span: str) -> int:
+    """Where ``span`` sits on an ``op_name`` path, at any depth (-1: not
+    on it)."""
+    at = path.find("/" + span + "/")
+    if at < 0 and path.startswith(span + "/"):
+        at = 0
+    return at
 
 
 def outermost_span(path: Optional[str],
@@ -47,9 +68,7 @@ def outermost_span(path: Optional[str],
         return None
     best, best_at = None, len(path) + 1
     for span in spans:
-        at = path.find("/" + span + "/")
-        if at < 0 and path.startswith(span + "/"):
-            at = 0
+        at = span_at(path, span)
         if 0 <= at < best_at:
             best, best_at = span, at
     return best
@@ -142,15 +161,18 @@ def _enclosing(modules: List[Tuple[float, float, str]], starts: List[float],
     return None
 
 
-def host_spans(pd, prefix: str = "bench/") -> List[Tuple[float, float, str]]:
-    """The benchmark's own host spans, (start, end, name)."""
+def host_spans(pd, names: Iterable[str] = (), prefix: str = "bench/"
+               ) -> List[Tuple[float, float, str]]:
+    """The benchmark's own host spans and the declared ``names``,
+    (start, end, name)."""
+    names = frozenset(names)
     out = []
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith(prefix):
+                if e.name.startswith(prefix) or e.name in names:
                     out.append((e.start_ns, e.start_ns + e.duration_ns,
                                 e.name))
     return sorted(out)
@@ -216,24 +238,46 @@ def gaps(intervals: List[Tuple[float, float]], w0: float, w1: float):
 
 
 def name_gap(gap: Tuple[float, float], spans) -> str:
-    """What the host was doing in a gap: the innermost benchmark span
-    that covers the most of it."""
-    best, best_cover, best_len = "host: outside any span", 0.0, None
+    """What the host was doing in a gap: the innermost host span that
+    covers most of it (more than half), else the one that covers the
+    largest part, the shorter on a tie."""
+    covering = []
     for s, e, name in spans:
         cover = min(e, gap[1]) - max(s, gap[0])
-        if cover <= 0 or name == "bench/window":
-            continue
-        if cover > best_cover or (cover == best_cover
-                                  and (e - s) < best_len):
-            best, best_cover, best_len = name, cover, e - s
-    return best
+        if cover > 0 and name != "bench/window":
+            covering.append((cover, e - s, name))
+    if not covering:
+        return "host: outside any span"
+    most = [c for c in covering if 2 * c[0] > gap[1] - gap[0]]
+    if most:
+        return min(most, key=lambda c: c[1])[2]
+    return max(covering, key=lambda c: (c[0], -c[1]))[2]
 
 
-def reduce(trace_dir: str, rec: Dict[str, Any], devices) -> Dict[str, Any]:
+def host_span_totals(spans, names: Iterable[str], w0: float, w1: float):
+    """({name: seconds}, {name: count}) of the ``names`` host spans that
+    overlap [w0, w1], their time cut to it."""
+    names = frozenset(names)
+    secs: Dict[str, float] = defaultdict(float)
+    count: Dict[str, int] = defaultdict(int)
+    for s, e, name in spans:
+        cover = min(e, w1) - max(s, w0)
+        if name in names and cover > 0:
+            secs[name] += cover * 1e-9
+            count[name] += 1
+    return dict(secs), dict(count)
+
+
+def reduce(trace_dir: str, rec: Dict[str, Any], devices,
+           spans: Iterable[str] = (), host: Iterable[str] = ()
+           ) -> Dict[str, Any]:
     """Everything the per-layer readers need from one traced window;
-    ``rec`` is the driver's record (rounds, work counts, HLO texts)."""
+    ``rec`` is the driver's record (rounds, work counts, HLO texts,
+    counters); ``spans`` and ``host`` are the device and host spans the
+    readers declare."""
+    spans, host = tuple(spans), tuple(host)
     pd = load(trace_dir)
-    spans_host = host_spans(pd)
+    spans_host = host_spans(pd, host)
     win = [(s, e) for s, e, n in spans_host if n == "bench/window"]
     if not win:
         raise RuntimeError("the trace holds no bench/window span")
@@ -245,6 +289,7 @@ def reduce(trace_dir: str, rec: Dict[str, Any], devices) -> Dict[str, Any]:
         raise RuntimeError("the trace holds no device op")
     n_dev = len(per_dev)
     span_s: Dict[str, float] = defaultdict(float)
+    span_in: Dict[str, float] = defaultdict(float)
     busy_total, attributed, provenanced, op_total = 0.0, 0.0, 0.0, 0.0
     op_time: Dict[str, float] = defaultdict(float)
     first_dev = min(per_dev)
@@ -266,15 +311,22 @@ def reduce(trace_dir: str, rec: Dict[str, Any], devices) -> Dict[str, Any]:
             if span is not None:
                 attributed += dur
                 span_s[span] += dur
+            for declared in spans:
+                if path and span_at(path, declared) >= 0:
+                    span_in[declared] += dur
             op_time[f"{o.name} [{span or 'no span'}]"] += dur / n_dev
         if dev == first_dev:
             dev_gaps = gaps(intervals, w0, w1)
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:10]
     top_gaps = sorted(dev_gaps, key=lambda g: g[0] - g[1])[:10]
+    host_s, host_n = host_span_totals(spans_host, host, w0, w1)
     return {
         "window_s": (w1 - w0) * 1e-9,
         "busy_s": busy_total / n_dev * 1e-9,
         "span_s": {k: v / n_dev for k, v in span_s.items()},
+        "span_in_s": {k: v / n_dev for k, v in span_in.items()},
+        "host_span_s": host_s,
+        "host_span_n": host_n,
         "op_s": op_total / n_dev,
         "coverage": attributed / op_total if op_total else 0.0,
         "provenanced": provenanced / op_total if op_total else 0.0,
@@ -285,5 +337,6 @@ def reduce(trace_dir: str, rec: Dict[str, Any], devices) -> Dict[str, Any]:
             "idle_gaps": [[name_gap(g, spans_host), (g[1] - g[0]) * 1e-9]
                           for g in top_gaps]},
         "work": rec.get("work", {}),
+        "counters": rec.get("counters", {}),
         "device_kind": devices[0].device_kind,
     }

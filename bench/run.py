@@ -4,7 +4,9 @@
 
 The cell is found by name in ``BENCHMARK.json``; its configuration, traffic
 mix and correctness limits are data files under ``bench/``, and each
-per-layer metric is a reader of its own under ``bench/metrics/``.  With
+per-layer metric is a reader of its own under ``bench/metrics/``, which
+may declare the device and host spans it reads (``SPANS``,
+``HOST_SPANS``).  With
 ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
 same window.  Every run checks what its first rounds produced against a
@@ -38,13 +40,22 @@ def parse_args(argv=None):
 
 
 def load_reader(name: str):
-    """The per-layer metric ``name``'s reader, ``bench/metrics/<name>.py``."""
+    """The per-layer metric ``name``'s reader module,
+    ``bench/metrics/<name>.py``: its ``read(ctx)`` and the spans it
+    declares."""
     path = os.path.join(BENCH_DIR, "metrics", name + ".py")
     spec = importlib.util.spec_from_file_location(
         "bench_metric_" + name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def declared(modules, key: str):
+    """The union, in first-declared order, of the names the modules
+    declare under ``key``."""
+    return tuple(dict.fromkeys(n for m in modules
+                               for n in getattr(m, key, ())))
 
 
 def layer_metrics(spec, cell_name: str):
@@ -60,12 +71,14 @@ def main(argv=None, *, require_tpu: bool = True) -> int:
 
     spec = common.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = common.load_cell(args.workload, spec)
-    readers = ({m["name"]: load_reader(m["name"])
-                for m in layer_metrics(spec, cell.name)}
-               if args.trace else {})
+    mods = ({m["name"]: load_reader(m["name"])
+             for m in layer_metrics(spec, cell.name)}
+            if args.trace else {})
     result = driver.run_cell(cell, args.seed, args.seconds, bool(args.trace),
-                             readers, t_process=T_PROCESS,
-                             require_tpu=require_tpu)
+                             {n: m.read for n, m in mods.items()},
+                             t_process=T_PROCESS, require_tpu=require_tpu,
+                             spans=declared(mods.values(), "SPANS"),
+                             host=declared(mods.values(), "HOST_SPANS"))
     units = {m["name"]: m["unit"]
              for m in spec["end_to_end"] + spec["per_layer"]}
     result["metrics"] = {k: {"value": v, "unit": units[k]}

@@ -50,13 +50,6 @@ def test_sound_tiny_run_is_correct():
     assert run_tiny(tiny.fl_cell())["correct"] is True
 
 
-@pytest.mark.parametrize("plant", [state_unchanged_round, half_batch_loss])
-def test_fl_round_fault_is_caught(monkeypatch, plant):
-    plant(monkeypatch)
-    out = run_tiny(tiny.fl_cell())
-    assert out["correct"] is False, out["checks"]
-
-
 def test_fl_round_without_the_exchange_is_caught():
     """Four cohorts on four host devices with the cross-cohort sum left
     out (every psum of the round hands back the chip's own value)."""
@@ -98,8 +91,15 @@ def fleet_half_batch(monkeypatch):
     monkeypatch.setattr(cnn.CNNModel, "loss", half)
 
 
-@pytest.mark.parametrize("plant", [fleet_unchanged, fleet_half_batch])
-def test_fleet_fault_is_caught(monkeypatch, plant):
+FAULTS = [(tiny.fl_cell, state_unchanged_round),
+          (tiny.fl_cell, half_batch_loss),
+          (tiny.fleet_cell, fleet_unchanged),
+          (tiny.fleet_cell, fleet_half_batch)]
+
+
+@pytest.mark.parametrize("cell,plant", FAULTS,
+                         ids=[p.__name__ for _, p in FAULTS])
+def test_fault_is_caught(monkeypatch, cell, plant):
     plant(monkeypatch)
-    out = run_tiny(tiny.fleet_cell())
+    out = run_tiny(cell())
     assert out["correct"] is False, out["checks"]

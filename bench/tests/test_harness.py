@@ -1,5 +1,6 @@
 """The harness is driven by data: every cell, traffic mix, configuration,
 limit set and per-layer reader is a file found by name."""
+import importlib.util
 import os
 import re
 import shutil
@@ -38,6 +39,8 @@ def test_names_units_and_bounds():
                for m in SPEC["end_to_end"])
     cells = {w["name"] for w in SPEC["workloads"]}
     for m in SPEC["per_layer"]:
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
         assert m["moves"] in {e["name"] for e in SPEC["end_to_end"]}
         assert set(m["workloads"]) <= cells
 
@@ -48,7 +51,7 @@ def test_every_cell_loads_from_its_files():
     for w in SPEC["workloads"]:
         assert NAME.match(w["name"]) and len(w["why"]) <= 200
         cell = common.load_cell(w["name"], SPEC)
-        assert cell.traffic["driver"] in ("fl_round", "fleet_scan")
+        assert importlib.util.find_spec("benchkit." + cell.traffic["driver"])
         assert cell.limits
         pairs.add((w["config"], w["traffic"]))
         four += w["chips"] == 4
@@ -60,7 +63,11 @@ def test_every_per_layer_metric_has_a_reader():
     sys.path.insert(0, common.BENCH_DIR)
     import run
     for m in SPEC["per_layer"]:
-        assert callable(run.load_reader(m["name"]))
+        mod = run.load_reader(m["name"])
+        assert callable(mod.read)
+        for key in ("SPANS", "HOST_SPANS"):
+            assert all(isinstance(s, str) and s
+                       for s in getattr(mod, key, ())), (m["name"], key)
 
 
 def test_each_cell_reports_an_end_to_end_metric_besides_setup():
@@ -77,6 +84,35 @@ def test_configuration_files_state_their_source_and_cuts():
         data = common.load_json(os.path.join(ROOT, c["file"]))
         assert data["source"] == c["source"]
         assert data["reduced"] == c["reduced"]
+
+
+def test_every_fl_round_configuration_names_its_architecture():
+    from benchkit import fl_round
+    for w in SPEC["workloads"]:
+        cell = common.load_cell(w["name"], SPEC)
+        if cell.traffic["driver"] != "fl_round":
+            continue
+        mod = fl_round.arch(cell)
+        assert os.path.dirname(mod.__file__) == os.path.join(
+            common.BENCH_DIR, "benchkit")
+        for name in fl_round.ARCH_API:
+            assert callable(getattr(mod, name)), (cell.config_name, name)
+
+
+@pytest.mark.parametrize("sizes,ok", [
+    ({"n_layers": 24, "moe.num_experts": 32, "moe.experts_per_token": 8},
+     True),
+    ({"moe.num_experts": 64}, False),
+    ({"n_layers": 27}, False),
+])
+def test_check_model_reads_dotted_keys(sizes, ok):
+    from repro.configs import get_config
+    cfg = get_config("granite-moe-1b-a400m")
+    if ok:
+        common.check_model(cfg, sizes)
+    else:
+        with pytest.raises(ValueError, match=re.escape(next(iter(sizes)))):
+            common.check_model(cfg, sizes)
 
 
 def test_without_a_tpu_the_run_exits_non_zero():

@@ -47,12 +47,20 @@ def test_fl_round_four_cohorts_matches_the_reference():
     assert r["change_gap"] < tiny.TINY_FL_LIMITS["change_gap"]
 
 
-def test_fleet_chunk_matches_the_reference():
+@pytest.mark.parametrize("fleet,seed", [(True, 7), (False, 9)],
+                         ids=["fleet", "without_a_fleet"])
+def test_fleet_chunk_matches_the_reference(fleet, seed):
+    """With the fleet, and as the homogeneous cohort (``fleet.size`` 0)
+    that a fleet-bypassing cell would run."""
     cell = tiny.fleet_cell()
-    sim, params, _, _, read = fleet_scan.first_chunk(cell, 7)
+    if not fleet:
+        for k in ("fleet.size", "power.policy", "fleet.selection"):
+            cell.traffic["overrides"].pop(k)
+    sim, params, _, _, read = fleet_scan.first_chunk(cell, seed)
     del sim, params
-    r = fleet_scan.compare(read, fleet_scan.reference(cell, 7))
+    r = fleet_scan.compare(read, fleet_scan.reference(cell, seed))
     assert r["survivors_gap"] == 0.0 and r["selected_mismatch"] == 0.0
+    assert fleet or r["fleet_gap"] == 0.0
     for name in ("loss_gap", "fleet_gap", "change_gap"):
         assert r[name] < tiny.TINY_FLEET_LIMITS[name], (name, r)
 
@@ -69,16 +77,3 @@ def test_bf16_dot_rounds_its_inputs():
     assert float(ref_olmo.f32_dot("ij,jk->ik", x, x)[0, 0]) != \
         float(ref_olmo.bf16_dot("ij,jk->ik", x, x)[0, 0])
 
-
-def test_paper_chunk_without_a_fleet_matches_the_reference():
-    """The homogeneous cohort (``fleet.size`` 0) that a fleet-bypassing
-    cell would run."""
-    cell = tiny.fleet_cell()
-    for k in ("fleet.size", "power.policy", "fleet.selection"):
-        cell.traffic["overrides"].pop(k)
-    sim, params, _, _, read = fleet_scan.first_chunk(cell, 9)
-    del sim, params
-    r = fleet_scan.compare(read, fleet_scan.reference(cell, 9))
-    assert r["survivors_gap"] == 0.0 and r["fleet_gap"] == 0.0
-    assert r["loss_gap"] < tiny.TINY_FLEET_LIMITS["loss_gap"]
-    assert r["change_gap"] < tiny.TINY_FLEET_LIMITS["change_gap"]

@@ -2,23 +2,9 @@
 ``data/tpu_v5e_fl_round``, pinned to the values the reduction gave when
 the program's simulator spans (``sim/*``) were added.  A later change to
 the join may add spans and metrics; it must not move these."""
-import glob
-import gzip
-import json
-import os
-import shutil
-
 import pytest
 
 from benchkit import readers
-from benchkit import trace_reduce as tr
-
-CHIP = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
-                    "tpu_v5e_fl_round")
-
-#: shape-only work counts for the readers that need them (made up, fixed)
-WORK = {"flops_per_round": 1.0e9, "wire_hbm_bytes": 4.0e6,
-        "wire_ici_bytes": 0.0, "fleet_bytes": 3.2e7}
 
 PINNED = {
     "coverage": 0.884630944261032,
@@ -34,21 +20,8 @@ PINNED = {
 
 
 @pytest.fixture(scope="module")
-def ctx(tmp_path_factory):
-    tmp = tmp_path_factory.mktemp("chip_trace")
-    meta = json.load(open(os.path.join(CHIP, "meta.json")))
-    with gzip.open(os.path.join(CHIP, "trace.xplane.pb.gz")) as src, \
-            open(tmp / "trace.xplane.pb", "wb") as dst:
-        shutil.copyfileobj(src, dst)
-    hlo = {}
-    for p in glob.glob(os.path.join(CHIP, "*.hlo.txt.gz")):
-        with gzip.open(p, "rt") as f:
-            hlo[os.path.basename(p)[:-len(".hlo.txt.gz")]] = f.read()
-
-    class Dev:
-        device_kind = "TPU v5 lite"
-    return tr.reduce(str(tmp), {"rounds": meta["rounds"], "hlo": hlo,
-                                "work": dict(WORK)}, [Dev()])
+def ctx(chip_ctx):
+    return chip_ctx[1]
 
 
 @pytest.mark.parametrize("name", ["coverage", "provenanced", "busy_s",
@@ -64,6 +37,17 @@ def test_reader_pinned(ctx, name):
                                                         rel=1e-12, abs=0)
 
 
+def test_declared_outer_span_reads_its_layer(ctx):
+    """Nothing encloses fl/local_steps, so declared at any depth it reads
+    what the outermost join gives it."""
+    assert ctx["span_in_s"]["fl/local_steps"] == \
+        ctx["span_s"]["fl/local_steps"]
+    assert readers.span_in_ms(ctx, ("fl/local_steps",)) == \
+        readers.local_steps_ms(ctx)
+
+
 def test_fleet_readers_read_nothing_in_a_round_trace(ctx):
     assert readers.fleet_ms(ctx) is None
     assert readers.fleet_roofline(ctx) is None
+    assert readers.span_in_ms(ctx, ("sim/client_update",)) is None
+    assert readers.host_span_count(ctx, ("sim/sync",)) is None

@@ -42,9 +42,10 @@ def test_olmo_param_count(config, layers, count):
                        common.load_json(f"{common.BENCH_DIR}/traffic/"
                                         "long_local.json"), {})
     cfg = fl_round.build_config(cell)
-    dims = fl_round.model_dims(cfg)
+    dims = fl_round.arch(cell).dims(cfg)
     assert dims["n_layers"] == layers
     assert work.olmo_param_count(dims) == count
+    assert fl_round.arch(cell).param_count(dims) == count
     assert cfg.model.param_count() == count
     shapes = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
     assert sum(int(np.prod(s.shape))

@@ -22,7 +22,7 @@ def fl_cell(cohorts: int = 1) -> common.Cell:
     return common.Cell(
         name="tiny.fl", config_name="tiny", traffic_name="tiny",
         chips=cohorts,
-        config={"arch": "olmo-1b",
+        config={"arch": "olmo-1b", "reference": "ref_olmo",
                 "model": {"n_layers": 2, "d_model": 128},
                 "overrides": dict(TINY_MODEL)},
         traffic={"driver": "fl_round",
